@@ -107,7 +107,7 @@ def policy_grid() -> list[tuple[str, float, str]]:
 
 
 def vector_sweep() -> list[tuple[str, float, str]]:
-    from repro.runtime.vector_backend import simulate_scalar
+    from repro.runtime.vector_backend import reference_gaps, simulate_scalar
 
     n_seeds = 128
     base = lab.Scenario(
@@ -137,11 +137,9 @@ def vector_sweep() -> list[tuple[str, float, str]]:
         slot, works, powers, cfg, _ = backend.compile([scenarios[i]],
                                                       backend.default_dt)
         sm = simulate_scalar(slot[0], works[0], powers, cfg)
-        for k, v in sm.items():
-            b = float(results[i][k])
-            err = abs(b - v) / max(abs(v), 1e-12)
-            max_err = max(max_err, err)
-            assert err < 1e-6, (i, k, b, v)
+        # raises outside the float32 engine's stated tolerance
+        gaps = reference_gaps(results[i].metrics, sm, cfg.n_slots)
+        max_err = max(max_err, *gaps.values())
     us_scalar = (time.perf_counter() - t0) / len(sample) * 1e6
 
     mean_resp = float(np.mean([r["mean_response"] for r in results]))

@@ -31,7 +31,6 @@ LOWER_IS_BETTER = (
     "mean_resp",
     "p99_resp",
     "mean_wait",
-    "max_rel_err",
     "overhead",
     "tier0_wait",      # constrained-trace priority-0 wait (PR 4)
     "tier0_p99",

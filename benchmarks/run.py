@@ -10,75 +10,30 @@ Run: ``PYTHONPATH=src python -m benchmarks.run [--only SUBSTR] [--json PATH]``
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import math
 import sys
 import traceback
 
 
+SUITES = ("paper", "dispatch", "kernels", "balance", "ablation", "runtime",
+          "federation", "traces", "fidelity", "evictions", "obs", "dag",
+          "serve")
+
+
 def _suites():
-    from . import bench_paper
-    suites = [("paper", bench_paper.ALL)]
-    try:
-        from . import bench_dispatch
-        suites.append(("dispatch", bench_dispatch.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_kernels
-        suites.append(("kernels", bench_kernels.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_balance
-        suites.append(("balance", bench_balance.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_ablation
-        suites.append(("ablation", bench_ablation.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_runtime
-        suites.append(("runtime", bench_runtime.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_federation
-        suites.append(("federation", bench_federation.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_traces
-        suites.append(("traces", bench_traces.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_fidelity
-        suites.append(("fidelity", bench_fidelity.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_evictions
-        suites.append(("evictions", bench_evictions.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_obs
-        suites.append(("obs", bench_obs.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_dag
-        suites.append(("dag", bench_dag.ALL))
-    except ImportError:
-        pass
-    try:
-        from . import bench_serve
-        suites.append(("serve", bench_serve.ALL))
-    except ImportError:
-        pass
+    """``(name, benchmark functions, import error)`` per suite. A suite that
+    fails to import comes back with its error, and the run counts it as a
+    failed suite."""
+    suites = []
+    for name in SUITES:
+        try:
+            mod = importlib.import_module(f".bench_{name}", __package__)
+        except Exception as exc:  # noqa: BLE001 — reported, counted failed
+            suites.append((name, [], exc))
+        else:
+            suites.append((name, mod.ALL, None))
     return suites
 
 
@@ -117,10 +72,17 @@ def main() -> None:
                              "{name, us_per_call, derived} records")
     args = parser.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     records = []
     failures = 0
-    for suite_name, fns in _suites():
+    for suite_name, fns, import_error in _suites():
+        if import_error is not None:
+            failures += 1
+            print(f"{suite_name},NaN,IMPORT ERROR", file=sys.stderr)
+            traceback.print_exception(import_error)
+            continue
         for fn in fns:
             if args.only and args.only not in f"{suite_name}/{fn.__name__}":
                 continue

@@ -92,7 +92,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     "causal", "window", "softcap", "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal=True, window=None,
                            softcap=None, block_q=128, block_k=512,
-                           interpret=True):
+                           interpret=False):
     """q: (B, H, S, hd); k, v: (B, KV, S, hd) with H % KV == 0.
     Returns (B, H, S, hd)."""
     b, h, s, hd = q.shape

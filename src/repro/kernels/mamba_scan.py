@@ -54,7 +54,7 @@ def _mamba_kernel(da_ref, dbx_ref, o_ref, h_ref, *, block_t):
 @functools.partial(jax.jit, static_argnames=("block_t", "block_d",
                                              "interpret"))
 def mamba_scan_pallas(da: jax.Array, dbx: jax.Array, *, block_t: int = 128,
-                      block_d: int = 256, interpret: bool = True):
+                      block_d: int = 256, interpret: bool = False):
     """da, dbx: (B, S, N, di). Returns h: (B, S, N, di) float32."""
     b, s, n, di = da.shape
     block_t = min(block_t, s)

@@ -5,7 +5,7 @@ Row-wise exclusive cumsum over the last axis. Grid = (row blocks, column
 blocks); column blocks run innermost (TPU grids iterate the trailing axis
 fastest and sequentially), carrying the running row totals in a VMEM scratch
 — the classic reduce/downsweep carry pattern with the in-block scan on the
-VPU.
+VPU as a log-depth doubling ladder (Mosaic has no cumsum lowering).
 
 Block shape: (block_rows, block_cols) in VMEM; block_cols a multiple of 128
 (lane width).
@@ -20,7 +20,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["prefix_scan_pallas"]
+__all__ = ["prefix_scan_pallas", "block_scan"]
+
+
+def _shift(x, s: int, axis: int):
+    """``x`` moved ``s`` places toward higher indices along ``axis``; zeros
+    enter at the low end."""
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (s, 0)
+    return jax.lax.slice_in_dim(jnp.pad(x, pad), 0, x.shape[axis], axis=axis)
+
+
+def block_scan(x, axis: int):
+    """Inclusive scan of an in-VMEM block along ``axis``: a Hillis-Steele
+    doubling ladder, log2(n) shifted adds."""
+    shift = 1
+    while shift < x.shape[axis]:
+        x = x + _shift(x, shift, axis)
+        shift *= 2
+    return x
 
 
 def _scan_kernel(x_ref, o_ref, carry_ref):
@@ -31,17 +49,16 @@ def _scan_kernel(x_ref, o_ref, carry_ref):
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
     x = x_ref[...]                                  # (br, bc)
-    carry = carry_ref[...]                          # (br, 1)
-    inc = jnp.cumsum(x, axis=1)
-    o_ref[...] = inc - x + carry
-    carry_ref[...] = carry + inc[:, -1:]
+    inc = block_scan(x, 1) + carry_ref[...]         # carry: (br, 1)
+    o_ref[...] = inc - x
+    carry_ref[...] = inc[:, -1:]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "block_cols", "interpret"))
 def prefix_scan_pallas(x: jax.Array, *, block_rows: int = 8,
                        block_cols: int = 512,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """Exclusive prefix sum along the last axis of ``x``: (rows, n)."""
     rows, n = x.shape
     block_rows = min(block_rows, rows)

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .prefix_scan import block_scan
+
 __all__ = ["dispatch_positions_pallas", "dispatch_work_prefix_pallas"]
 
 _LANES = 128
@@ -33,7 +35,7 @@ def _dispatch_kernel(e_ref, base_ref, pos_ref, fill_ref, acc_ref):
     e = e_ref[...]                                   # (bt, 1) int32
     eids = jax.lax.broadcasted_iota(jnp.int32, (e.shape[0], _LANES), 1)
     onehot = (e == eids).astype(jnp.int32)           # (bt, E_pad) in VMEM
-    cum = jnp.cumsum(onehot, axis=0) - onehot        # exclusive scan
+    cum = block_scan(onehot, 0) - onehot             # exclusive scan
     acc = acc_ref[...]                               # (1, E_pad)
     pos = ((cum + acc) * onehot).sum(axis=1, keepdims=True)
     pos_ref[...] = pos
@@ -45,7 +47,7 @@ def _dispatch_kernel(e_ref, base_ref, pos_ref, fill_ref, acc_ref):
                    static_argnames=("n_experts", "block_tokens", "interpret"))
 def dispatch_positions_pallas(expert_idx: jax.Array, base: jax.Array, *,
                               n_experts: int, block_tokens: int = 256,
-                              interpret: bool = True):
+                              interpret: bool = False):
     """expert_idx: (T,) int32 destination per token; base: (E,) already
     filled. Returns (positions (T,), fill (E,)) — fill includes base."""
     t = expert_idx.shape[0]
@@ -86,7 +88,7 @@ def _work_prefix_kernel(e_ref, w_ref, pos_ref, fill_ref, acc_ref):
     eids = jax.lax.broadcasted_iota(jnp.int32, (e.shape[0], _LANES), 1)
     onehot = (e == eids).astype(w.dtype)             # (bt, E_pad) in VMEM
     ww = onehot * w                                  # weight routed per lane
-    cum = jnp.cumsum(ww, axis=0) - ww                # exclusive weighted scan
+    cum = block_scan(ww, 0) - ww                     # exclusive weighted scan
     acc = acc_ref[...]                               # (1, E_pad)
     pos_ref[0] = ((cum + acc) * onehot).sum(axis=1, keepdims=True)
     acc_ref[...] = acc + ww.sum(axis=0, keepdims=True)
@@ -97,7 +99,7 @@ def _work_prefix_kernel(e_ref, w_ref, pos_ref, fill_ref, acc_ref):
                    static_argnames=("n_experts", "block_tokens", "interpret"))
 def dispatch_work_prefix_pallas(expert_idx: jax.Array, weights: jax.Array, *,
                                 n_experts: int, block_tokens: int = 256,
-                                interpret: bool = True):
+                                interpret: bool = False):
     """Weighted variant of :func:`dispatch_positions_pallas`, batched over
     rows: ``expert_idx`` (R, T) int32 destination per token (-1 = none),
     ``weights`` (R, T) work units. Returns ``(prefix (R, T), fill (R, E))``

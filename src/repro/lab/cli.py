@@ -21,6 +21,7 @@ import json
 import sys
 from pathlib import Path
 
+from ..compile_cache import enable_compile_cache
 from ..federation import Federation, TopologySpec
 from ..serve import backend as _serve_backend  # noqa: F401 — registers "online"
 from .api import BATCH_THRESHOLD, expand_grid, run, sweep
@@ -445,6 +446,7 @@ def main(argv: list[str] | None = None) -> int:
                       help="write the constraints JSON sidecar here")
 
     args = parser.parse_args(argv)
+    enable_compile_cache()
 
     if args.cmd == "trace":
         return _trace_cmd(args)

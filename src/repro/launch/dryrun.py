@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs import SHAPES, arch_shape_cells, get_config
 from repro.configs.base import ModelConfig, ShapeSpec
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import collective_stats, roofline_report
 from repro.launch.shardings import (
     activation_rules,
@@ -88,7 +88,7 @@ def _lower_one(cfg: ModelConfig, shape: ShapeSpec, multi_pod: bool,
     specs = input_specs(cfg, shape)
     t0 = time.time()
 
-    with set_mesh(mesh), logical_axis_rules(rules):
+    with jax.set_mesh(mesh), logical_axis_rules(rules):
         if shape.kind == "train":
             opt = AdamW(moments_dtype=dtype_of(cfg.moments_dtype))
             sch = warmup_cosine(3e-4, 100, 10_000)
@@ -123,8 +123,6 @@ def _lower_one(cfg: ModelConfig, shape: ShapeSpec, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns [dict]
-        cost = cost[0] if cost else {}
     coll = collective_stats(compiled.as_text())
     record = {
         "arch": cfg.name,

@@ -13,26 +13,12 @@ from __future__ import annotations
 
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
-
-__all__ = ["make_production_mesh", "elastic_mesh", "mesh_axis_sizes",
-           "set_mesh"]
-
-
-def set_mesh(mesh):
-    """Context manager binding ``mesh``: ``jax.set_mesh`` on jax >= 0.6,
-    the ``Mesh`` context itself on older releases."""
-    fn = getattr(jax, "set_mesh", None)
-    return fn(mesh) if fn is not None else mesh
+__all__ = ["make_production_mesh", "elastic_mesh", "mesh_axis_sizes"]
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

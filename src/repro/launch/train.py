@@ -13,10 +13,11 @@ from __future__ import annotations
 import argparse
 import json
 
+import jax
 
 from repro.configs import get_config
 from repro.data import DocStream, Pipeline
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.shardings import activation_rules
 from repro.models import LM
 from repro.models.common import dtype_of, logical_axis_rules
@@ -71,7 +72,7 @@ def main():
     if args.mesh != "none":
         mesh = make_production_mesh(multi_pod=args.mesh == "multi")
         rules = activation_rules(cfg, mesh)
-        with set_mesh(mesh), logical_axis_rules(rules):
+        with jax.set_mesh(mesh), logical_axis_rules(rules):
             state, history = train(lm, opt, sch, pipe, loop, monitor=monitor)
     else:
         state, history = train(lm, opt, sch, pipe, loop, monitor=monitor)

@@ -14,10 +14,9 @@ Two families of invariants:
   eventually completes, and wasted service is exactly the progress churn
   destroyed. The same holds federation-wide with WAN exchange on top.
 
-Property-based tests run under hypothesis (via ``tests/_hypothesis_compat``)
-with a bounded, derandomized profile so CI wall time stays flat; the
-deterministic companions keep the invariants covered when hypothesis is not
-installed.
+Property-based tests run under hypothesis with a bounded, derandomized
+profile so CI wall time stays flat; deterministic companions pin the same
+invariants on fixed examples.
 """
 
 from __future__ import annotations
@@ -29,7 +28,8 @@ from repro import lab
 from repro.runtime import ClusterRuntime
 from repro.traces import Evictions, TraceSchema
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 # bounded, derandomized: identical examples on every CI run, ~seconds of
 # wall time (the batched backend recompiles per workload shape)
@@ -368,8 +368,6 @@ def test_batched_rejects_eviction_traces_with_reason(tmp_path):
 def test_hypothesis_profile_is_bounded():
     """The CI fast subset includes this file: the property profiles must
     stay small enough to keep wall time ~flat."""
-    if not HAVE_HYPOTHESIS:
-        pytest.skip("hypothesis not installed")
     assert FAST_PROFILE["max_examples"] <= 10
     assert CHEAP_PROFILE["max_examples"] <= 25
     assert FAST_PROFILE["derandomize"] and CHEAP_PROFILE["derandomize"]

@@ -4,7 +4,8 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import HyperGrid, embed, factorize, optimal_dim
 from repro.core.cost_model import scan_steps

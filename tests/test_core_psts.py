@@ -2,7 +2,8 @@
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import HyperGrid, embed, psts_schedule
 

@@ -2,7 +2,8 @@
 
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import exclusive_scan, exclusive_scan_np, inclusive_scan_np
 
@@ -63,10 +64,7 @@ def f(x):
     exc, tot = axis_exclusive_scan(x, "x", 8)
     return exc, tot
 
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map
-exc, tot = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"),
+exc, tot = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"),
                              out_specs=(P("x"), P("x"))))(vals)
 want = np.concatenate([[0.0], np.cumsum(vals)[:-1]])
 assert np.allclose(np.asarray(exc), want), (exc, want)

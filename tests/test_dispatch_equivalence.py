@@ -4,7 +4,8 @@ weights) — the §Perf optimization changes traffic, never routing."""
 
 import jax
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.sched.moe_dispatch import dispatch
 

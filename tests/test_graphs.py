@@ -9,7 +9,8 @@ import json
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.graphs import DAG_KINDS, DagSpec, make_dag
 from repro.obs import Tracer
 from repro.runtime.runtime import ClusterRuntime

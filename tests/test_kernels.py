@@ -1,19 +1,11 @@
-"""Pallas kernel validation: shape/dtype sweeps against the ref.py oracles
-(interpret=True executes the kernel bodies on CPU)."""
+"""Pallas kernel validation: shape/dtype sweeps against the ref.py oracles,
+through ``ops`` (which interprets the kernel bodies on CPU)."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.mamba_scan import mamba_scan_pallas
-from repro.kernels.prefix_scan import prefix_scan_pallas
-from repro.kernels.psts_dispatch import (
-    dispatch_positions_pallas,
-    dispatch_work_prefix_pallas,
-)
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 # ---------------------------------------------------------------------------
@@ -25,7 +17,7 @@ from repro.kernels import ops
 def test_prefix_scan_shapes(rows, n, bc):
     x = jnp.asarray(np.random.default_rng(0).normal(size=(rows, n)),
                     jnp.float32)
-    got = prefix_scan_pallas(x, block_cols=bc)
+    got = ops.prefix_scan(x, block_cols=bc)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref.prefix_scan_ref(x)),
                                rtol=1e-4, atol=1e-4)
@@ -35,7 +27,7 @@ def test_prefix_scan_shapes(rows, n, bc):
 def test_prefix_scan_dtypes(dtype):
     x = jnp.asarray(np.random.default_rng(1).integers(0, 9, size=(3, 257)),
                     dtype)
-    got = prefix_scan_pallas(x, block_cols=64)
+    got = ops.prefix_scan(x, block_cols=64)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref.prefix_scan_ref(x)))
 
@@ -50,7 +42,7 @@ def test_dispatch_positions_shapes(t, e, bt):
     rng = np.random.default_rng(t + e)
     e_idx = jnp.asarray(rng.integers(0, e, size=t), jnp.int32)
     base = jnp.asarray(rng.integers(0, 3, size=e), jnp.int32)
-    pos, fill = dispatch_positions_pallas(e_idx, base, n_experts=e,
+    pos, fill = ops.dispatch_positions(e_idx, base, n_experts=e,
                                           block_tokens=bt)
     pos_r, fill_r = ref.dispatch_positions_ref(e_idx, base, e)
     np.testing.assert_array_equal(np.asarray(pos), np.asarray(pos_r))
@@ -62,7 +54,7 @@ def test_dispatch_positions_matches_moe_layer_semantics():
     earlier same-expert tokens + base."""
     e_idx = jnp.asarray([2, 0, 2, 2, 1, 0], jnp.int32)
     base = jnp.asarray([10, 0, 5], jnp.int32)
-    pos, fill = dispatch_positions_pallas(e_idx, base, n_experts=3,
+    pos, fill = ops.dispatch_positions(e_idx, base, n_experts=3,
                                           block_tokens=4)
     assert list(np.asarray(pos)) == [5, 10, 6, 7, 0, 11]
     assert list(np.asarray(fill)) == [12, 1, 8]
@@ -75,7 +67,7 @@ def test_dispatch_work_prefix_shapes(r, t, e, bt):
     e_idx = rng.integers(-1, e, size=(r, t)).astype(np.int32)
     w = rng.exponential(2.0, size=(r, t)).astype(np.float32)
     w[e_idx < 0] = 0.0
-    pos, fill = dispatch_work_prefix_pallas(
+    pos, fill = ops.dispatch_work_prefix(
         jnp.asarray(e_idx), jnp.asarray(w), n_experts=e, block_tokens=bt)
     # oracle: running per-destination weight in token order, per row
     pos_r = np.zeros((r, t), np.float32)
@@ -96,9 +88,9 @@ def test_dispatch_work_prefix_unit_weights_match_positions():
     """With unit weights the weighted prefix IS the positional scan."""
     rng = np.random.default_rng(9)
     e_idx = rng.integers(0, 5, size=200).astype(np.int32)
-    pos_i, fill_i = dispatch_positions_pallas(
+    pos_i, fill_i = ops.dispatch_positions(
         jnp.asarray(e_idx), jnp.zeros(5, jnp.int32), n_experts=5)
-    pos_w, fill_w = dispatch_work_prefix_pallas(
+    pos_w, fill_w = ops.dispatch_work_prefix(
         jnp.asarray(e_idx[None, :]), jnp.ones((1, 200), jnp.float32),
         n_experts=5)
     np.testing.assert_allclose(np.asarray(pos_w)[0], np.asarray(pos_i))
@@ -116,7 +108,7 @@ def test_flash_attention_gqa_shapes(h, kv, s, hd):
     q = jnp.asarray(rng.normal(size=(2, h, s, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(2, kv, s, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(2, kv, s, hd)), jnp.float32)
-    got = flash_attention_pallas(q, k, v, block_q=64, block_k=64)
+    got = ops.flash_attention(q, k, v, block_q=64, block_k=64)
     want = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -129,7 +121,7 @@ def test_flash_attention_window_softcap(window, softcap):
     q = jnp.asarray(rng.normal(size=(1, 2, 128, 32)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(1, 2, 128, 32)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(1, 2, 128, 32)), jnp.float32)
-    got = flash_attention_pallas(q, k, v, window=window, softcap=softcap,
+    got = ops.flash_attention(q, k, v, window=window, softcap=softcap,
                                  block_q=32, block_k=32)
     want = ref.flash_attention_ref(q, k, v, window=window, softcap=softcap)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -141,7 +133,7 @@ def test_flash_attention_bf16():
     q = jnp.asarray(rng.normal(size=(1, 2, 64, 32)), jnp.bfloat16)
     k = jnp.asarray(rng.normal(size=(1, 2, 64, 32)), jnp.bfloat16)
     v = jnp.asarray(rng.normal(size=(1, 2, 64, 32)), jnp.bfloat16)
-    got = flash_attention_pallas(q, k, v, block_q=32, block_k=32)
+    got = ops.flash_attention(q, k, v, block_q=32, block_k=32)
     want = ref.flash_attention_ref(q, k, v)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -160,7 +152,7 @@ def test_flash_attention_matches_model_attention():
     pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     xla = chunked_attention(q, k, v, q_positions=pos, kv_positions=pos,
                             block=32)
-    pal = flash_attention_pallas(q.transpose(0, 2, 1, 3),
+    pal = ops.flash_attention(q.transpose(0, 2, 1, 3),
                                  k.transpose(0, 2, 1, 3),
                                  v.transpose(0, 2, 1, 3),
                                  block_q=32, block_k=32)
@@ -178,7 +170,7 @@ def test_mamba_scan_shapes(s, di, bt, bd):
     rng = np.random.default_rng(s + di)
     da = jnp.asarray(rng.uniform(0.6, 1.0, size=(2, s, 4, di)), jnp.float32)
     dbx = jnp.asarray(rng.normal(size=(2, s, 4, di)), jnp.float32)
-    got = mamba_scan_pallas(da, dbx, block_t=bt, block_d=bd)
+    got = ops.mamba_scan(da, dbx, block_t=bt, block_d=bd)
     want = ref.mamba_scan_ref(da, dbx)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -192,7 +184,7 @@ def test_mamba_scan_matches_model_chunked_scan():
     dbx = jnp.asarray(rng.normal(size=(b, s, di, n)), jnp.float32)
     model_h, _ = selective_scan_chunked(da, dbx, chunk=16)
     # kernel layout is (B,S,N,di)
-    kern_h = mamba_scan_pallas(da.transpose(0, 1, 3, 2),
+    kern_h = ops.mamba_scan(da.transpose(0, 1, 3, 2),
                                dbx.transpose(0, 1, 3, 2),
                                block_t=16, block_d=32)
     np.testing.assert_allclose(np.asarray(kern_h.transpose(0, 1, 3, 2)),
@@ -204,8 +196,12 @@ def test_mamba_scan_matches_model_chunked_scan():
 # ---------------------------------------------------------------------------
 
 def test_ops_backend_selection():
+    """``ops`` runs the kernel, interpreted off-TPU and compiled for a TPU
+    (``tests/test_tpu_compile.py`` shows the compiled side)."""
+    import jax
     x = jnp.ones((2, 64))
-    np.testing.assert_allclose(np.asarray(ops.prefix_scan(x, backend="ref")),
-                               np.asarray(ops.prefix_scan(x,
-                                                          backend="pallas")))
-    assert not ops.on_tpu()  # this container is CPU — auto == ref
+    np.testing.assert_allclose(np.asarray(ops.prefix_scan(x)),
+                               np.asarray(ref.prefix_scan_ref(x)))
+    text = jax.jit(ops.prefix_scan).lower(x).as_text()
+    assert jax.default_backend() != "tpu"
+    assert "tpu_custom_call" not in text  # CPU lowering: the interpreter

@@ -35,7 +35,8 @@ from repro.serve import (
     WorkloadSource,
 )
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from test_conformance import POWERS, _churn_inputs
 
 STREAM_PROFILE = dict(max_examples=12, deadline=None, derandomize=True)

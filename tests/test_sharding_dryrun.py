@@ -52,21 +52,16 @@ batch = {'tokens': tokens, 'labels': tokens}
 s1, m1 = jax.jit(step)(state, batch)
 
 # sharded
-try:
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((4, 2), ('data', 'model'),
-                         axis_types=(AxisType.Auto,) * 2)
-except ImportError:  # jax < 0.5
-    mesh = jax.make_mesh((4, 2), ('data', 'model'))
-set_mesh = getattr(jax, 'set_mesh', None)
-mesh_ctx = set_mesh(mesh) if set_mesh is not None else mesh
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                     axis_types=(AxisType.Auto,) * 2)
 from repro.launch.shardings import (activation_rules, batch_pspecs,
                                     state_pspecs, named)
 from repro.configs.base import SHAPES
 rules = activation_rules(cfg, mesh)
 state_shapes = jax.eval_shape(lambda: init_state(lm, opt, jax.random.key(0)))
 st_sh = named(mesh, state_pspecs(state_shapes, cfg, mesh))
-with mesh_ctx, logical_axis_rules(rules):
+with jax.set_mesh(mesh), logical_axis_rules(rules):
     s2, m2 = jax.jit(step, in_shardings=(st_sh, None),
                      out_shardings=(st_sh, None))(state, batch)
 d1 = float(m1['loss']); d2 = float(m2['loss'])

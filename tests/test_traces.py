@@ -31,7 +31,8 @@ from repro.traces import (
     write_normalized_csv,
 )
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 DATA = Path(__file__).parent / "data"
 G_EVENTS = DATA / "google_tiny_events.csv"
