@@ -54,7 +54,11 @@ def sweep(scenarios: list[Scenario] | None = None, *,
     ``>= batch_threshold`` batched-eligible scenarios to the batched backend
     in one call, and loops the events backend otherwise. Any explicit
     backend name forces that backend for every scenario.
+
+    The call is the profiler span ``repro.sweep`` (``scenarios`` counts
+    them), which holds the backends' own spans.
     """
+    from jax.profiler import TraceAnnotation
     if scenarios is None:
         if base is None:
             raise ValueError("sweep needs scenarios or base (+ grid)")
@@ -65,7 +69,14 @@ def sweep(scenarios: list[Scenario] | None = None, *,
         scenarios = list(scenarios)
     if not scenarios:
         return []
+    with TraceAnnotation("repro.sweep", scenarios=len(scenarios)):
+        return _dispatch(scenarios, backend, batch_threshold,
+                         backend_options)
 
+
+def _dispatch(scenarios: list[Scenario], backend: str, batch_threshold: int,
+              backend_options: dict) -> list[RunResult]:
+    """``sweep``'s choice of backend, and the runs."""
     batched = get_backend("batched")
     # federations (no .workload, their own backend) dispatch as a unit
     if all(getattr(sc, "is_federation", False) for sc in scenarios):
@@ -75,7 +86,7 @@ def sweep(scenarios: list[Scenario] | None = None, *,
             backend_options.pop("dt")  # slot width is batched-only
             warnings.warn("sweep dispatched to the 'federated' backend; "
                           "the batched-only 'dt' option is ignored",
-                          stacklevel=2)
+                          stacklevel=3)
         chosen = get_backend(backend)
         for sc in scenarios:  # fail fast, before any federation has run
             chosen.check(sc)
@@ -95,7 +106,7 @@ def sweep(scenarios: list[Scenario] | None = None, *,
         warnings.warn("trace workloads ignore the seed axis — these "
                       "scenarios replay the identical trace (give the "
                       "TraceRef a scale= to resample per seed)",
-                      stacklevel=2)
+                      stacklevel=3)
     uniform = (backend in ("auto", "batched")
                and uniform_but_for_seed(scenarios))
     if backend == "auto":
@@ -112,7 +123,7 @@ def sweep(scenarios: list[Scenario] | None = None, *,
         backend_options.pop("dt")  # slot width is batched-only
         warnings.warn(f"sweep dispatched to the {backend!r} backend; "
                       f"the batched-only 'dt' option is ignored",
-                      stacklevel=2)
+                      stacklevel=3)
     chosen = get_backend(backend)
     for sc in scenarios:  # fail fast, before any scenario has run
         chosen.check(sc)

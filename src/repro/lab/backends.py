@@ -395,7 +395,12 @@ class BatchedBackend(Backend):
                 fifo_dispatch: bool = False):
         """Shared lowering for run/run_many: (slot, works, powers, cfg,
         power_scale). All scenarios must share cluster/policy/faults/
-        workload shape (only seeds may differ)."""
+        workload shape (only seeds may differ).
+
+        Profiler spans: ``repro.batched.generate`` (each seed's workload;
+        ``tasks`` counts them) and ``repro.batched.quantize`` (tasks onto
+        slots, and the power schedule)."""
+        from jax.profiler import TraceAnnotation
         from ..runtime.vector_backend import VectorConfig
         from ..runtime.workload import batch_slots
         if not uniform_but_for_seed(scenarios):
@@ -406,7 +411,9 @@ class BatchedBackend(Backend):
         base = scenarios[0]
         powers = base.cluster.resolve_powers()
         n = int(powers.size)
-        wls = [sc.workload.materialize(sc.seed) for sc in scenarios]
+        with TraceAnnotation("repro.batched.generate") as span:
+            wls = [sc.workload.materialize(sc.seed) for sc in scenarios]
+            span.set_metadata(tasks=sum(wl.m for wl in wls))
         horizon = base.workload.horizon
         if horizon is None:  # whole-trace replay: cover the last arrival
             horizon = max((wl.horizon for wl in wls), default=0.0) + dt
@@ -441,8 +448,9 @@ class BatchedBackend(Backend):
             probe=(base.obs is not None
                    and base.obs.probe_every is not None),
             **cost)
-        slot, works, _ = batch_slots(wls, dt, n_slots)
-        scale = self._power_scale(base, n_slots, n, dt)
+        with TraceAnnotation("repro.batched.quantize"):
+            slot, works, _ = batch_slots(wls, dt, n_slots)
+            scale = self._power_scale(base, n_slots, n, dt)
         return slot, works, powers, cfg, scale
 
     @staticmethod
@@ -564,7 +572,9 @@ class BatchedBackend(Backend):
     def run_many(self, scenarios: list[Scenario],
                  *, dt: float | None = None,
                  fifo_dispatch: bool = False) -> list[RunResult]:
-        """The whole sweep as ONE ``simulate_batch`` call."""
+        """The whole sweep as ONE ``simulate_batch`` call; the results'
+        assembly is the profiler span ``repro.batched.results``."""
+        from jax.profiler import TraceAnnotation
         from ..runtime.vector_backend import simulate_batch
         if not scenarios:
             return []
@@ -603,11 +613,12 @@ class BatchedBackend(Backend):
                 extra_ignored.append(
                     "obs.probe_every cadence (fluid probes sample every "
                     "slot, i.e. every dt)")
-        return [self._result(sc, bm, i, cfg, fault_counts, extra_ignored,
-                             admitted_work=float(works[i].sum()),
-                             extras={"obs": self._obs_extras(bm, i, cfg)}
-                             if cfg.probe else None)
-                for i, sc in enumerate(scenarios)]
+        with TraceAnnotation("repro.batched.results"):
+            return [self._result(sc, bm, i, cfg, fault_counts, extra_ignored,
+                                 admitted_work=float(works[i].sum()),
+                                 extras={"obs": self._obs_extras(bm, i, cfg)}
+                                 if cfg.probe else None)
+                    for i, sc in enumerate(scenarios)]
 
 
 # ---------------------------------------------------------------------------

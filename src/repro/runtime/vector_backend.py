@@ -310,8 +310,9 @@ def _simulate_batch_jax(works, cnt, powers, scale, cfg: VectorConfig):
     # batched exclusive scan over all (scenario, slot) rows — the paper's
     # core operator, the Pallas prefix-scan kernel. Every row holds one
     # slot's arrivals, so no position is a difference of two long sums
-    mid = (ops.prefix_scan(works.reshape(B * T, K)).reshape(B, T, K)
-           + 0.5 * works)
+    with jax.named_scope("prefix_scan"):
+        mid = (ops.prefix_scan(works.reshape(B * T, K)).reshape(B, T, K)
+               + 0.5 * works)
     tot = works.sum(axis=2)                               # (B, T)
     lanes = jnp.arange(K)[None, :]
 
@@ -319,55 +320,63 @@ def _simulate_batch_jax(works, cnt, powers, scale, cfg: VectorConfig):
         queue, fires, moved, seen = carry
         t, work, mid_t, cnt_t = xs                        # (B, K), (B,)
         mask = lanes < cnt_t[:, None]                     # (B, K)
-        pw = powers * scale[t]                            # (B, n)
-        pi = pw.sum(axis=1, keepdims=True)
         # -- arrivals (the positional rule of _owner_np, batched)
-        tot_t = tot[:, t][:, None]                        # (B, 1)
-        fair = pw / pi * (queue.sum(axis=1, keepdims=True) + tot_t)
-        deficit = jnp.maximum(fair - queue, 0.0)
-        src = jnp.where(deficit.sum(axis=1, keepdims=True) > 0.0,
-                        deficit, pw)
-        owner = _owner(src, mid_t / jnp.where(tot_t > 0.0, tot_t, 1.0))
-        q_own = jnp.take_along_axis(queue, owner, axis=1)
-        pw_own = jnp.take_along_axis(pw, owner, axis=1)
+        with jax.named_scope("deficit"):
+            pw = powers * scale[t]                        # (B, n)
+            pi = pw.sum(axis=1, keepdims=True)
+            tot_t = tot[:, t][:, None]                    # (B, 1)
+            fair = pw / pi * (queue.sum(axis=1, keepdims=True) + tot_t)
+            deficit = jnp.maximum(fair - queue, 0.0)
+            src = jnp.where(deficit.sum(axis=1, keepdims=True) > 0.0,
+                            deficit, pw)
+        with jax.named_scope("owner_lookup"):
+            owner = _owner(src, mid_t / jnp.where(tot_t > 0.0, tot_t, 1.0))
+        with jax.named_scope("owner_gather"):
+            q_own = jnp.take_along_axis(queue, owner, axis=1)
+            pw_own = jnp.take_along_axis(pw, owner, axis=1)
         backlog_ahead = 0.0
         if cfg.fifo_dispatch:
             # fused dispatch kernel: exclusive same-owner work prefix of
             # this slot's dispatch wave, all B scenarios in one grid
-            backlog_ahead, _ = ops.dispatch_work_prefix(
-                jnp.where(mask, owner, -1).astype(jnp.int32),
-                jnp.where(mask, work, 0.0), n_experts=n)
-        resp = jnp.where(mask, (q_own + backlog_ahead + work)
-                         / jnp.maximum(pw_own, _TINY), 0.0)
-        queue = queue.at[rows, owner].add(jnp.where(mask, work, 0.0))
+            with jax.named_scope("dispatch"):
+                backlog_ahead, _ = ops.dispatch_work_prefix(
+                    jnp.where(mask, owner, -1).astype(jnp.int32),
+                    jnp.where(mask, work, 0.0), n_experts=n)
+        with jax.named_scope("owner_gather"):
+            resp = jnp.where(mask, (q_own + backlog_ahead + work)
+                             / jnp.maximum(pw_own, _TINY), 0.0)
+        with jax.named_scope("scatter_add"):
+            queue = queue.at[rows, owner].add(jnp.where(mask, work, 0.0))
         seen = seen + cnt_t
         # -- crossover trigger (and/or the probe's trigger signal — same
         # formulas as simulate_scalar, see the note there)
-        if cfg.rebalance or cfg.probe:
-            w = queue.sum(axis=1, keepdims=True)
-            t_bal = jnp.where(pi > 0.0, w / jnp.maximum(pi, _TINY), 0.0)
-            ratio = jnp.where(pw > 0.0, queue / jnp.maximum(pw, _TINY),
-                              jnp.where(queue > _TINY, jnp.inf, 0.0))
-            imb = ratio.max(axis=1, keepdims=True) \
-                / jnp.maximum(t_bal, _TINY) - 1.0
-            fair_q = pw / jnp.maximum(pi, _TINY) * w
-            excess = jnp.maximum(queue - fair_q, 0.0).sum(
-                axis=1, keepdims=True)
-            overhead = (cfg.scan_steps * (cfg.p + cfg.q)
-                        + seen[:, None] / n * cfg.t_task
-                        + excess * cfg.packets_per_unit
-                        / cfg.packets_per_step * cfg.p)
-            cross = overhead / jnp.maximum(t_bal, _TINY)
-            fire = (t_bal > _TINY) & (imb > jnp.maximum(cross, cfg.floor))
-            if cfg.rebalance:
-                queue = jnp.where(fire, fair_q, queue)
-                moved = moved + jnp.where(fire[:, 0], excess[:, 0], 0.0)
-                fires = fires + fire[:, 0].astype(jnp.float32)
-            else:
-                fire = jnp.zeros_like(fire)
+        with jax.named_scope("trigger"):
+            if cfg.rebalance or cfg.probe:
+                w = queue.sum(axis=1, keepdims=True)
+                t_bal = jnp.where(pi > 0.0, w / jnp.maximum(pi, _TINY), 0.0)
+                ratio = jnp.where(pw > 0.0, queue / jnp.maximum(pw, _TINY),
+                                  jnp.where(queue > _TINY, jnp.inf, 0.0))
+                imb = ratio.max(axis=1, keepdims=True) \
+                    / jnp.maximum(t_bal, _TINY) - 1.0
+                fair_q = pw / jnp.maximum(pi, _TINY) * w
+                excess = jnp.maximum(queue - fair_q, 0.0).sum(
+                    axis=1, keepdims=True)
+                overhead = (cfg.scan_steps * (cfg.p + cfg.q)
+                            + seen[:, None] / n * cfg.t_task
+                            + excess * cfg.packets_per_unit
+                            / cfg.packets_per_step * cfg.p)
+                cross = overhead / jnp.maximum(t_bal, _TINY)
+                fire = (t_bal > _TINY) & (imb > jnp.maximum(cross, cfg.floor))
+                if cfg.rebalance:
+                    queue = jnp.where(fire, fair_q, queue)
+                    moved = moved + jnp.where(fire[:, 0], excess[:, 0], 0.0)
+                    fires = fires + fire[:, 0].astype(jnp.float32)
+                else:
+                    fire = jnp.zeros_like(fire)
         # -- service (backlog sampled before draining, as in simulate_scalar)
-        busy = queue.sum(axis=1)
-        queue_next = jnp.maximum(queue - pw * cfg.dt, 0.0)
+        with jax.named_scope("service"):
+            busy = queue.sum(axis=1)
+            queue_next = jnp.maximum(queue - pw * cfg.dt, 0.0)
         if cfg.probe:
             ys = (busy, resp, queue, imb[:, 0], cross[:, 0], fire[:, 0])
         else:
@@ -384,20 +393,24 @@ def _simulate_batch_jax(works, cnt, powers, scale, cfg: VectorConfig):
 
     count = cnt.sum(axis=1).astype(jnp.float32)
     resp = resp.transpose(1, 0, 2)                          # (B, T, K)
-    mean = jnp.where(count > 0, resp.sum(axis=(1, 2))
-                     / jnp.maximum(count, 1.0), jnp.nan)
+    with jax.named_scope("summary"):
+        mean = jnp.where(count > 0, resp.sum(axis=(1, 2))
+                         / jnp.maximum(count, 1.0), jnp.nan)
     # nearest-rank p99 with padding pushed to +inf
-    valid = lanes[None] < cnt[:, :, None]
-    s = jnp.sort(jnp.where(valid, resp, jnp.inf).reshape(B, T * K), axis=1)
-    k = jnp.clip(jnp.ceil(0.99 * count).astype(jnp.int32), 1,
-                 jnp.maximum(count.astype(jnp.int32), 1))
-    p99 = jnp.where(count > 0,
-                    jnp.take_along_axis(s, (k - 1)[:, None], axis=1)[:, 0],
-                    jnp.nan)
+    with jax.named_scope("p99_sort"):
+        valid = lanes[None] < cnt[:, :, None]
+        s = jnp.sort(jnp.where(valid, resp, jnp.inf).reshape(B, T * K),
+                     axis=1)
+        k = jnp.clip(jnp.ceil(0.99 * count).astype(jnp.int32), 1,
+                     jnp.maximum(count.astype(jnp.int32), 1))
+        p99 = jnp.where(
+            count > 0, jnp.take_along_axis(s, (k - 1)[:, None], axis=1)[:, 0],
+            jnp.nan)
     # makespan: last slot with backlog, +1 slot, in time units
-    busy = (backlog > _TINY).astype(jnp.int32)              # (T, B)
-    last = (jnp.arange(T)[:, None] + 1) * busy
-    makespan = last.max(axis=0).astype(jnp.float32) * cfg.dt
+    with jax.named_scope("summary"):
+        busy = (backlog > _TINY).astype(jnp.int32)          # (T, B)
+        last = (jnp.arange(T)[:, None] + 1) * busy
+        makespan = last.max(axis=0).astype(jnp.float32) * cfg.dt
     out = (mean, p99, makespan, fires, moved, count)
     if cfg.probe:
         # scan stacks along the leading (time) axis; hand back batch-major
@@ -410,15 +423,24 @@ def device_args(slot: np.ndarray, works: np.ndarray, powers: np.ndarray,
                 cfg: VectorConfig, power_scale: np.ndarray | None = None):
     """The batched program's operands ``(works (B, T, K), cnt (B, T),
     powers (B, n), scale (T, n))`` for (B, M) slot-sorted task rows, as
-    ``simulate_batch`` passes them."""
-    works, cnt = _per_slot(slot, works, cfg.n_slots)
+    ``simulate_batch`` passes them.
+
+    Profiler spans: ``repro.vector.layout`` (the per-slot layout; its
+    ``tasks`` are the arrivals laid out, ``lanes`` the B * T * K
+    positions that hold them, ``K`` the lanes a slot) and
+    ``repro.vector.transfer`` (the copies to the device)."""
+    with jax.profiler.TraceAnnotation("repro.vector.layout") as span:
+        works, cnt = _per_slot(slot, works, cfg.n_slots)
+        span.set_metadata(tasks=int(cnt.sum()), lanes=works.size,
+                          K=works.shape[2])
     powers = np.asarray(powers, dtype=np.float32)
     if powers.ndim == 1:
         powers = np.broadcast_to(powers, (works.shape[0], powers.shape[0]))
     scale = (np.ones((cfg.n_slots, cfg.n_nodes), np.float32)
              if power_scale is None else power_scale)
-    return (jnp.asarray(works), jnp.asarray(cnt), jnp.asarray(powers),
-            jnp.asarray(scale, dtype=jnp.float32))
+    with jax.profiler.TraceAnnotation("repro.vector.transfer"):
+        return (jnp.asarray(works), jnp.asarray(cnt), jnp.asarray(powers),
+                jnp.asarray(scale, dtype=jnp.float32))
 
 
 def simulate_batch(slot: np.ndarray, works: np.ndarray, powers: np.ndarray,
@@ -428,10 +450,16 @@ def simulate_batch(slot: np.ndarray, works: np.ndarray, powers: np.ndarray,
 
     ``slot``/``works``: (B, M); ``powers``: (n,) or (B, n);
     ``power_scale``: optional (T, n) shared up/down schedule.
+
+    Profiler spans: ``repro.vector.run`` (the program's dispatch) and
+    ``repro.vector.fetch`` (the wait for the device and the copy of the
+    results to the host); ``device_args`` marks layout and transfer.
     """
-    out = _simulate_batch_jax(
-        *device_args(slot, works, powers, cfg, power_scale), cfg)
-    out = tuple(map(np.asarray, out))
+    args = device_args(slot, works, powers, cfg, power_scale)
+    with jax.profiler.TraceAnnotation("repro.vector.run"):
+        out = _simulate_batch_jax(*args, cfg)
+    with jax.profiler.TraceAnnotation("repro.vector.fetch"):
+        out = tuple(map(np.asarray, out))
     mean, p99, makespan, fires, moved, count = out[:6]
     probes = (dict(zip(("probe_queue", "probe_imbalance",
                         "probe_crossover", "probe_fires"), out[6:]))
