@@ -33,6 +33,7 @@ cluster.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 
@@ -271,14 +272,57 @@ def reference_gaps(got: dict, ref: dict, n_slots: int) -> dict:
 # Batched JAX engine
 # ---------------------------------------------------------------------------
 
-def _owner(src, frac):
-    """``_owner_np`` batched over rows: src (B, n), frac (B, M). Rows
-    without arrivals this slot are clipped into range (and masked later)."""
+def _cut_points(src, frac):
+    """Each row's cut points ``edges`` (B, n) and its queries ``x`` (B, M),
+    as in ``_owner_np``."""
     edges = jnp.cumsum(src, axis=1)
     last = edges[:, -1:]
-    x = jnp.minimum(frac * last, jnp.nextafter(last, 0.0))
-    owner = jax.vmap(lambda e, v: jnp.searchsorted(e, v, side="right"))(
+    return edges, jnp.minimum(frac * last, jnp.nextafter(last, 0.0))
+
+
+def _owner_search(edges, x):
+    """Binary search: ``searchsorted(side="right")`` of each row's queries."""
+    return jax.vmap(lambda e, v: jnp.searchsorted(e, v, side="right"))(
         edges, x)
+
+
+def _owner_count(edges, x):
+    """The same owners as ``_owner_search``, as the number of cut points at
+    or below each query: one fused compare and sum over the node axis,
+    with no gather and no loop. Both are non-negative and finite here, so
+    the plain ``<=`` orders them as ``searchsorted``'s comparator does.
+
+    The TPU's reduction runs through the (8, 128) tiles of its output four
+    at a time where their number allows, else two or one at a time: on a
+    v5e, 8 rows of 2,944 queries (23 tiles) took five times as long as 8
+    of 3,072 (24 tiles). So the queries are padded to a multiple of four
+    tiles, and the padding's counts dropped."""
+    rows, k = x.shape
+    row_tiles = -(-rows // 8)
+    lanes = 128 * (4 // math.gcd(4, row_tiles))
+    xp = jnp.pad(x, ((0, 0), (0, -k % lanes)))
+    count = jnp.sum(edges[:, :, None] <= xp[:, None, :], axis=1,
+                    dtype=jnp.int32)
+    return count[:, :k]
+
+
+def _owner(src, frac):
+    """``_owner_np`` batched over rows: src (B, n), frac (B, M). Rows
+    without arrivals this slot are clipped into range (and masked later).
+
+    The method follows the platform the program is lowered for; both give
+    the same integers. A binary search does O(M log n) work in ceil(log2
+    (n + 1)) rounds, each a gather of one cut point per query. On the CPU
+    that is cheap and cache-friendly: there the count ran 20 to 60 times
+    slower. On a TPU a gathered element costs about as much as 10**4
+    elementwise compares, so the search is bound by its gathers; counting
+    does O(M n) compares but keeps them on the vector units, fused, and
+    nothing of size (B, M, n) reaches memory. On a v5e, 256 lookups of 32
+    rows of 640 queries among 4,000 nodes took 654 ms by search and 34 ms
+    by count."""
+    edges, x = _cut_points(src, frac)
+    owner = jax.lax.platform_dependent(edges, x, tpu=_owner_count,
+                                       default=_owner_search)
     return jnp.clip(owner, 0, src.shape[1] - 1)
 
 

@@ -27,7 +27,13 @@ from repro.runtime import (
     simulate_scalar,
     sweep_seeds,
 )
-from repro.runtime.vector_backend import _owner, _owner_np, reference_gaps
+from repro.runtime.vector_backend import (
+    _cut_points,
+    _owner,
+    _owner_count,
+    _owner_np,
+    reference_gaps,
+)
 
 POWERS = np.array([3.0, 1.0, 7.0, 2.0, 5.0, 9.0, 4.0, 6.0,
                    2.0, 8.0, 1.0, 5.0, 3.0, 6.0, 4.0, 7.0])
@@ -190,6 +196,76 @@ def test_owner_rule_float32_flips(n, max_rate):
     for r, i in flips:
         lo, hi = sorted((got[r, i], want[r, i]))
         assert (src[r, lo:hi + 1] > 0).sum() == 2  # neighbours
+
+
+def _owner_case(name):
+    """(edges, x) of one row batch, built to tell a count of cut points
+    from a binary search wherever the two could part."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(sum(map(ord, name)))
+
+    def midpoints(rows, k):
+        w = rng.uniform(0.0, 12.0, size=(rows, k))
+        return ((np.cumsum(w, 1) - 0.5 * w) / w.sum(1, keepdims=True)
+                ).astype(np.float32)
+
+    def widths(rows, n, zeros=0.4):
+        src = rng.uniform(0.0, 5.0, size=(rows, n))
+        return np.where(rng.uniform(size=(rows, n)) < zeros, 0.0,
+                        src).astype(np.float32)
+
+    if name == "on_cut":
+        # integer widths: the cut points are exact, and so are the queries
+        # placed on them and one ulp either side
+        src = rng.integers(0, 4, size=(4, 300)).astype(np.float32)
+        edges = np.cumsum(src, axis=1)
+        x = edges[:, rng.integers(0, 300, size=256)]
+        x = np.concatenate([x, np.nextafter(x, np.float32(0.0)),
+                            np.nextafter(x, np.float32(np.inf))], axis=1)
+        return jnp.asarray(edges), jnp.asarray(x)
+    if name == "zero_runs":
+        src = widths(4, 4000)
+        src[:, :37] = src[:, -41:] = src[:, 1000:1200] = 0.0
+        frac = midpoints(4, 640)
+        frac[:, 0], frac[:, -1] = 0.0, 1.0
+    elif name == "no_arrivals":
+        # a slot without arrivals puts every query at 0 (mid 0 over 1);
+        # at frac 1 the query sits one ulp below the top cut point
+        src = widths(4, 4000)
+        frac = np.repeat(np.array([[0.0], [1.0], [0.0], [1.0]], np.float32),
+                         128, axis=1)
+    elif name == "padding":
+        # padding lanes sit at frac 1, past the row's last task
+        src = widths(4, 4000)
+        frac = np.concatenate([midpoints(4, 100), np.ones((4, 156))],
+                              axis=1).astype(np.float32)
+    elif name == "all_down":
+        # pi = 0: the widths fall back to the powers, all zero
+        src = np.zeros((4, 4000), np.float32)
+        frac = midpoints(4, 640)
+    elif name == "n1":
+        src = np.array([[2.5], [0.0], [1.0], [7.0]], np.float32)
+        frac = midpoints(4, 128)
+    else:                                   # "n130": not a lane multiple
+        src = widths(4, 130)
+        frac = midpoints(4, 384)
+    return _cut_points(jnp.asarray(src), jnp.asarray(frac))
+
+
+@pytest.mark.parametrize("case", ["on_cut", "zero_runs", "no_arrivals",
+                                  "padding", "all_down", "n1", "n130"])
+def test_owner_count_matches_binary_search(case):
+    """The TPU's owner lookup, a count of cut points at or below each
+    query, gives ``searchsorted(side="right")``'s integers element for
+    element."""
+    import jax
+    import jax.numpy as jnp
+    edges, x = _owner_case(case)
+    want = np.asarray(jax.vmap(lambda e, v: jnp.searchsorted(
+        e, v, side="right", method="scan"))(edges, x))
+    got = np.asarray(jax.jit(_owner_count)(edges, x))
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
 
 
 def test_vector_matches_scalar_deployment_size():

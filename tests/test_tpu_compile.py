@@ -3,13 +3,15 @@
 The TPU compiler ships with libtpu and compiles for a chip that is only
 described. These tests compile the sweep's kernels and the whole batched
 program at the shapes of ``chip_smoke.py`` and check that each Pallas
-kernel lowered to a Mosaic ``tpu_custom_call`` (not the interpreter) and
-that the program fits the chip's 16 GiB. The topology is described inside a
-fixture, never at import: only one process may hold libtpu, and test
-workers import every test file.
+kernel lowered to a Mosaic ``tpu_custom_call`` (not the interpreter), that
+the program fits the chip's 16 GiB, and that its owner lookup gathers and
+loops nothing. The topology is described inside a fixture, never at
+import: only one process may hold libtpu, and test workers import every
+test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,3 +84,8 @@ def test_sweep_program_compiles_for_v5e(one_chip, n_nodes, per_slot, fifo,
         ((SEEDS, SLOTS, per_slot), jnp.float32), ((SEEDS, SLOTS), jnp.int32),
         ((SEEDS, n_nodes), jnp.float32), ((SLOTS, n_nodes), jnp.float32))
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    lookup = [line for line in text.splitlines()
+              if re.search(r'op_name="[^"]*/owner_lookup/', line)]
+    assert lookup
+    assert not [line for line in lookup
+                if re.search(r"\s(gather|while)\(", line)]
