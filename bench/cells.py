@@ -7,6 +7,19 @@ The traffic generator is general: a mix names an arrival process of the
 reference generators, an offered load as a share of the cluster's
 capacity, arrival rates as multiples of the base rate
 ``lambda = load * sum(powers) / work_mean`` and fixed process parameters.
+
+A deployment gives its cluster in one of two forms:
+
+* ``power_low``, ``power_high``, ``power_seed``: integer powers drawn
+  uniformly from ``power_seed``, as the program's ``ClusterSpec`` draws
+  them from ``n_nodes``;
+* ``machines``, a table of ``{"count", "power"}`` classes (a trace's
+  machine table). It is expanded in table order and then permuted by
+  ``power_seed``, because a trace's machine IDs are not grouped by
+  platform; the program is handed exactly these powers.
+
+``nodes`` is the cluster's size in both forms, so a table's counts add up
+to it.
 """
 
 from __future__ import annotations
@@ -53,7 +66,12 @@ class Cell:
         return self.settings["limits"]
 
     def powers(self) -> np.ndarray:
+        """(nodes,) float64 node powers, in node order."""
         c = self.config
+        if "machines" in c:
+            table = np.repeat([float(m["power"]) for m in c["machines"]],
+                              [int(m["count"]) for m in c["machines"]])
+            return np.random.default_rng(c["power_seed"]).permutation(table)
         return ref_workload.cluster_powers(c["nodes"], c["power_low"],
                                            c["power_high"], c["power_seed"])
 
@@ -80,12 +98,59 @@ class Cell:
 
     def shrunk(self) -> "Cell":
         """The same cell at a size the CPU rehearsal can run."""
-        config = dict(self.config, nodes=min(self.config["nodes"], 64),
-                      slots=16)
+        nodes = min(self.config["nodes"], 64)
+        config = dict(self.config, nodes=nodes, slots=16)
+        if "machines" in config:
+            config["machines"] = shrink_table(config["machines"], nodes)
         settings = dict(self.settings, seeds_per_sweep=4,
                         pool_sweeps=min(self.pool_sweeps, 6),
                         check_sample=min(self.settings["check_sample"], 2))
         return Cell(self.name, self.chips, config, self.traffic, settings)
+
+
+def shrink_table(machines: list[dict], nodes: int) -> list[dict]:
+    """A machine table cut to ``nodes`` machines: every class keeps at
+    least one, and the rest are shared out in the table's proportions by
+    largest remainder."""
+    counts = np.array([int(m["count"]) for m in machines])
+    if nodes >= counts.sum():
+        return list(machines)
+    if nodes < len(machines):
+        raise ValueError(f"{nodes} machines cannot hold {len(machines)} "
+                         f"classes")
+    quota = nodes * counts / counts.sum()
+    kept = np.maximum(np.floor(quota).astype(int), 1)
+    by_remainder = np.argsort(-(quota - np.floor(quota)), kind="stable")
+    for i in by_remainder[:max(nodes - int(kept.sum()), 0)]:
+        kept[i] += 1
+    while kept.sum() > nodes:   # the minimums took seats past the quotas
+        i = min(np.flatnonzero(kept > 1), key=lambda i: quota[i] - kept[i])
+        kept[i] -= 1
+    return [dict(m, count=int(k)) for m, k in zip(machines, kept)]
+
+
+def check_cluster(config: dict, file: str) -> None:
+    """Refuse a deployment whose cluster is not given in exactly one form,
+    or whose machine table does not add up to ``nodes``."""
+    uniform = {"power_low", "power_high"} & set(config)
+    if "power_seed" not in config:
+        raise ValueError(f"{file}: the cluster needs a power_seed")
+    if "machines" in config:
+        if uniform:
+            raise ValueError(f"{file}: give the cluster as a machine table "
+                             f"or by {sorted(uniform)}, not both")
+        rows = config["machines"]
+        if not rows or any(int(m["count"]) < 1 or float(m["power"]) <= 0
+                           for m in rows):
+            raise ValueError(f"{file}: every machine class needs a count of "
+                             f"1 or more and a power above 0")
+        total = sum(int(m["count"]) for m in rows)
+        if total != config["nodes"]:
+            raise ValueError(f"{file}: the machine table holds {total} "
+                             f"machines, nodes says {config['nodes']}")
+    elif len(uniform) != 2:
+        raise ValueError(f"{file}: give the cluster as a machine table or "
+                         f"by power_low and power_high")
 
 
 def find(name: str, root: Path = ROOT) -> Cell:
@@ -97,8 +162,9 @@ def find(name: str, root: Path = ROOT) -> Cell:
                          f"{[w['name'] for w in spec['workloads']]}")
     conf = next(c for c in spec["configs"] if c["name"] == entry["config"])
     bench = root / "bench"
-    return Cell(name=name, chips=int(entry["chips"]),
-                config=_load(root / conf["file"]),
+    config = _load(root / conf["file"])
+    check_cluster(config, conf["file"])
+    return Cell(name=name, chips=int(entry["chips"]), config=config,
                 traffic=_load(bench / "traffic" / f"{entry['traffic']}.json"),
                 settings=_load(bench / "cells" / f"{name}.json"))
 

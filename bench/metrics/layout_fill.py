@@ -2,15 +2,13 @@
 over the ``lanes`` (seeds x slots x K) that the program's
 ``repro.vector.layout`` spans count in the window."""
 
-from bench import spans
-
 LAYER = "runtime.vector_backend sweep program"
 UNIT = "%"
 MOVES = "sim_tasks_per_s"
 
 
 def read(run):
-    sp = spans.of(run)
+    sp = run.spans
     args = sp.span_args.get("repro.vector.layout", {}) if sp else {}
     if not args.get("lanes"):
         return None

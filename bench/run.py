@@ -18,6 +18,14 @@ window), ``device``, with ``--trace 1`` a ``breakdown``, and last the
 numbers compared for ``correct``, each beside its limit (also the last
 lines of standard error).
 
+A traced run reads the trace once (``xplane.load``) for the harness's own
+reduction (``xplane.reduce``: device busy time, ops, kernels, idle gaps by
+innermost ``bench.*`` or ``repro.*`` span) and for the program's spans
+and device scopes (``spans.reduce``), whose scopes need the optimized HLO
+of each program shape the window ran: after the window, untimed and
+outside the compile count, it lowers and compiles those shapes again (a
+cache hit) for their text.
+
 * Set-up (``setup_s``, from the start of the process to the start of the
   window): JAX and the chip, one warm-up sweep on seeds of its own, then an
   ahead-of-time compile of every further program shape the pool's sweeps
@@ -61,7 +69,7 @@ if str(ROOT / "src") not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from bench import cells, check, xplane  # noqa: E402
+from bench import cells, check, spans, xplane  # noqa: E402
 
 # JAX's persistent compilation cache, at a fixed path inside the checkout
 CACHE_DIR = ROOT / ".jax_cache"
@@ -95,6 +103,7 @@ class Window:
     compiles: int
     device_kind: str
     trace: xplane.Trace | None = None
+    spans: spans.Spans | None = None
 
 
 class CompileCounter:
@@ -122,10 +131,15 @@ class CompileCounter:
 
 def scenarios(lab, cell: cells.Cell, seeds: list[int]):
     c, kw = cell.config, cell.workload_kwargs()
+    if "machines" in c:
+        cluster = lab.ClusterSpec(powers=tuple(cell.powers()))
+    else:
+        cluster = lab.ClusterSpec(n_nodes=c["nodes"],
+                                  power_low=c["power_low"],
+                                  power_high=c["power_high"],
+                                  power_seed=c["power_seed"])
     base = lab.Scenario(
-        cluster=lab.ClusterSpec(n_nodes=c["nodes"], power_low=c["power_low"],
-                                power_high=c["power_high"],
-                                power_seed=c["power_seed"]),
+        cluster=cluster,
         workload=lab.WorkloadSpec(
             process=kw["process"], horizon=kw["horizon"],
             work_dist=kw["work_dist"], work_mean=kw["work_mean"],
@@ -140,76 +154,58 @@ def sweep(lab, cell: cells.Cell, scs) -> list[dict]:
     return [{k: float(r.metrics[k]) for k in METRIC_KEYS} for r in results]
 
 
+def program_shapes(lab, cell: cells.Cell, plans: list[np.ndarray]):
+    """``(program, args, cfg)`` for each distinct argument shape of the
+    sweep program that ``plans`` lay out to, in order of first use;
+    ``plans`` holds each sweep's (seeds, slots) arrivals per slot. The
+    shapes come from the program's own layout
+    (``vector_backend.device_args``) of a batch whose rows hold their
+    busiest slot's arrivals."""
+    from repro.runtime import vector_backend as vb
+    one = scenarios(lab, cell, [0])
+    _, _, powers, cfg, scale = lab.get_backend("batched").compile(
+        one, cell.config["dt"])
+    seen = set()
+    for counts in plans:
+        busiest = counts.max(axis=1)
+        slot = np.full((counts.shape[0], max(int(busiest.max()), 1)),
+                       counts.shape[1], np.int32)
+        for b, k in enumerate(busiest):
+            slot[b, :k] = 0
+        args = vb.device_args(slot, np.ones(slot.shape), powers, cfg, scale)
+        key = tuple((a.shape, str(a.dtype)) for a in args)
+        if key not in seen:
+            seen.add(key)
+            yield vb._simulate_batch_jax, args, cfg
+        del args
+
+
 def warm_shapes(lab, cell: cells.Cell, plans: list[np.ndarray]) -> int:
     """Compile ahead of time the sweep program for every argument shape
-    the pool's sweeps need; ``plans`` holds each sweep's (seeds, slots)
-    arrivals per slot, the warm-up sweep's first. The shapes come from the
-    program's own layout (``vector_backend.device_args``) of a batch whose
-    rows hold their busiest slot's arrivals. Returns the programs compiled
-    here; raises :class:`Unmeasured` where the program no longer has what
-    this needs, as a window would then compile."""
+    the pool's sweeps need; ``plans`` holds each sweep's arrivals, the
+    warm-up sweep's first, whose shape that sweep compiled. Returns the
+    programs compiled here; raises :class:`Unmeasured` where the program
+    no longer has what this needs, as a window would then compile."""
+    compiled = 0
     try:
-        from repro.runtime import vector_backend as vb
-        one = scenarios(lab, cell, [0])
-        _, _, powers, cfg, scale = lab.get_backend("batched").compile(
-            one, cell.config["dt"])
-        program = vb._simulate_batch_jax
-        seen, compiled = set(), 0
-        for j, counts in enumerate(plans):
-            busiest = counts.max(axis=1)
-            slot = np.full((counts.shape[0], max(int(busiest.max()), 1)),
-                           counts.shape[1], np.int32)
-            for b, k in enumerate(busiest):
-                slot[b, :k] = 0
-            args = vb.device_args(slot, np.ones(slot.shape), powers, cfg,
-                                  scale)
-            key = tuple((a.shape, str(a.dtype)) for a in args)
-            if j and key not in seen:
-                t0 = time.perf_counter()
-                program.lower(*args, cfg).compile()
-                compiled += 1
-                log(f"  compiled shape {[k[0] for k in key]} in "
-                    f"{time.perf_counter() - t0:.3f} s")
-            seen.add(key)
-            del args
+        shapes = program_shapes(lab, cell, plans)
+        next(shapes)
+        for program, args, cfg in shapes:
+            t0 = time.perf_counter()
+            program.lower(*args, cfg).compile()
+            compiled += 1
+            log(f"  compiled shape {[a.shape for a in args]} in "
+                f"{time.perf_counter() - t0:.3f} s")
     except (ImportError, AttributeError, TypeError, ValueError) as e:
         raise Unmeasured(f"warm-up by shape unavailable: {e!r}") from e
     return compiled
 
 
-@contextlib.contextmanager
-def host_spans(lab):
-    """Profiler annotations around the program's host phases, for the
-    traced run only: ``bench.lower`` (workload materialisation and slot
-    quantisation), ``bench.layout`` (per-slot layout and transfer) and
-    ``bench.simulate`` (the device program and the results' return).
-    A phase the program no longer has is left out."""
-    import jax
-    targets = []
-    with contextlib.suppress(ImportError, AttributeError):
-        targets.append((type(lab.get_backend("batched")), "compile",
-                        "bench.lower"))
-    with contextlib.suppress(ImportError):
-        from repro.runtime import vector_backend as vb
-        targets += [(vb, "device_args", "bench.layout"),
-                    (vb, "simulate_batch", "bench.simulate")]
-    saved = []
-    for owner, attr, span in targets:
-        fn = owner.__dict__.get(attr) if isinstance(owner, type) \
-            else getattr(owner, attr, None)
-        if fn is None:
-            continue
-
-        def wrapped(*a, _fn=fn, _span=span, **k):
-            with jax.profiler.TraceAnnotation(_span):
-                return _fn(*a, **k)
-        saved.append((owner, attr, fn))
-        setattr(owner, attr, wrapped)
-    try:
-        yield
-    finally:
-        for owner, attr, fn in saved:
-            setattr(owner, attr, fn)
+def hlo_texts(lab, cell: cells.Cell, plans: list[np.ndarray]) -> list[str]:
+    """Optimized HLO text of the sweep program at each shape of
+    ``plans``, the window's sweeps; each compile is a cache hit."""
+    return [program.lower(*args, cfg).compile().as_text()
+            for program, args, cfg in program_shapes(lab, cell, plans)]
 
 
 # ---------------------------------------------------------------------------
@@ -228,17 +224,21 @@ def per_layer(cell_name: str, run: Window, spec: dict) -> dict:
     return out
 
 
-def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
-        spec: dict, devices) -> dict:
-    """Set-up, window and check of one run; returns the result line."""
-    import jax
-    from repro import lab
-    dev = devices[0]
-    B = cell.seeds_per_sweep
+@dataclass
+class Plan:
+    """A run's sweeps of the cell's pool, in the order of its seed."""
 
+    seeds: list        # each sweep's scenario seeds
+    counts: list       # each sweep's (seeds, slots) reference arrivals
+    scenarios: list    # each sweep's scenarios
+
+
+def prepare(lab, cell: cells.Cell, seed: int) -> Plan:
+    """Set-up after JAX and the chip: the warm-up sweep, the pool's
+    arrivals counted and grouped, every further shape compiled."""
     log(f"cell {cell.name}: {cell.config['nodes']} nodes, "
-        f"{cell.config['slots']} slots, {B} seeds per sweep, "
-        f"traffic {cell.traffic['process']}")
+        f"{cell.config['slots']} slots, {cell.seeds_per_sweep} seeds per "
+        f"sweep, traffic {cell.traffic['process']}")
     warm_seeds = cells.warm_seeds(cell)
     t0 = time.perf_counter()
     with CompileCounter() as warm_compiles:
@@ -260,21 +260,28 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         f"it compiling; the pool's {len(plan_idx)} sweeps' arrivals counted "
         f"and grouped in {t_counts:.3f} s; {n_warmed} further shapes "
         f"compiled in {time.perf_counter() - t0:.3f} s")
-    plan = [scenarios(lab, cell, s) for s in plan_seeds]
+    return Plan(plan_seeds, counts,
+                [scenarios(lab, cell, s) for s in plan_seeds])
 
-    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
-    spans = host_spans(lab) if trace else contextlib.nullcontext()
-    note = (jax.profiler.TraceAnnotation if trace
+
+def window(lab, cell: cells.Cell, plan: Plan, seconds: float,
+           trace_dir=None):
+    """Sweeps of ``plan`` start while less than ``seconds`` have passed;
+    under the profiler, writing to ``trace_dir``, where one is given.
+    Returns ``(results per sweep, t_start, t_end)``; raises
+    :class:`Unmeasured` where a program compiled inside the window."""
+    import jax
+    note = (jax.profiler.TraceAnnotation if trace_dir
             else lambda _name: contextlib.nullcontext())
     results = []
-    with spans, CompileCounter() as counter:
-        if trace:
+    with CompileCounter() as counter:
+        if trace_dir:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
-            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
         t_start = time.perf_counter()
         with note(xplane.WINDOW):
-            for scs in plan:
+            for scs in plan.scenarios:
                 if time.perf_counter() - t_start >= seconds:
                     break
                 t0 = time.perf_counter()
@@ -282,36 +289,56 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
                     results.append(sweep(lab, cell, scs))
                 log(f"  sweep {len(results)}: {time.perf_counter() - t0:.6f} s")
         t_end = time.perf_counter()
-        compiles = counter.count
-        if trace:
+        if trace_dir:
             jax.profiler.stop_trace()
+    if len(results) == len(plan.scenarios) and t_end - t_start < seconds:
+        log(f"  the pool of {len(results)} sweeps ran out at "
+            f"{t_end - t_start:.3f} s")
+    if counter.count:
+        raise Unmeasured(f"{counter.count} programs compiled inside the "
+                         f"window")
+    return results, t_start, t_end
+
+
+def read_trace(trace_dir, texts: list[str]):
+    """``(xplane.Trace, spans.Spans)`` of the trace in ``trace_dir``, read
+    once; ``texts`` are the optimized HLO of the shapes the window ran."""
+    data = xplane.load(xplane.find_xplane(trace_dir))
+    return xplane.reduce(data), spans.reduce(data, texts)
+
+
+def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
+        spec: dict, devices) -> dict:
+    """Set-up, window and check of one run; returns the result line."""
+    from repro import lab
+    dev = devices[0]
+    plan = prepare(lab, cell, seed)
+    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+    try:
+        results, t_start, t_end = window(lab, cell, plan, seconds, trace_dir)
+        peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+        done = len(results)
+        counts = np.concatenate(plan.counts[:done])
+        win = Window(sweeps=done, tasks=int(counts.sum()), compiles=0,
+                     device_kind=dev.device_kind)
+        if trace:
+            t0 = time.perf_counter()
+            texts = hlo_texts(lab, cell, plan.counts[:done])
+            win.trace, win.spans = read_trace(trace_dir, texts)
+            log(f"  {len(texts)} programs' HLO and the trace read in "
+                f"{time.perf_counter() - t0:.3f} s")
+    finally:
+        if trace_dir:
+            shutil.rmtree(trace_dir, ignore_errors=True)
     setup_s = t_start - _T0
     window_s = t_end - t_start
-    done = len(results)
-    if done == len(plan) and window_s < seconds:
-        log(f"  the pool of {len(plan)} sweeps ran out at {window_s:.3f} s")
-    if compiles:
-        if trace:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-        raise Unmeasured(f"{compiles} programs compiled inside the window")
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
-
-    counts = np.concatenate(counts[:done])
-    tasks = int(counts.sum())
-    win = Window(sweeps=done, tasks=tasks,
-                 compiles=compiles, device_kind=dev.device_kind)
-    if trace:
-        t0 = time.perf_counter()
-        win.trace = xplane.reduce(xplane.find_xplane(trace_dir))
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        log(f"  trace read in {time.perf_counter() - t0:.3f} s")
-    log(f"  window {window_s:.6f} s: {done} sweeps, {done * B} scenarios, "
-        f"{tasks} tasks, {compiles} compiles; set-up {setup_s:.6f} s; "
-        f"peak {peak} bytes")
+    log(f"  window {window_s:.6f} s: {done} sweeps, "
+        f"{done * cell.seeds_per_sweep} scenarios, {win.tasks} tasks, "
+        f"no compiles; set-up {setup_s:.6f} s; peak {peak} bytes")
 
     t0 = time.perf_counter()
     flat = [m for res in results for m in res]
-    seeds = [x for ss in plan_seeds[:done] for x in ss]
+    seeds = [x for ss in plan.seeds[:done] for x in ss]
     readings, failed = check.judge(cell, flat, seeds, counts, seed)
     log(f"  check took {time.perf_counter() - t0:.3f} s")
 
@@ -321,7 +348,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         metrics = per_layer(cell.name, win, spec)
         device.update(busy_s=win.trace.busy_s, window_s=win.trace.window_s)
     else:
-        metrics = {"sim_tasks_per_s": {"value": tasks / window_s,
+        metrics = {"sim_tasks_per_s": {"value": win.tasks / window_s,
                                        "unit": "tasks/s"},
                    "setup_s": {"value": setup_s, "unit": "s"}}
     out = {"correct": check.passed(readings, cell.limits) and failed == 0,

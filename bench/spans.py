@@ -1,5 +1,5 @@
 """Reduction of the program's own spans and device scopes in a profiler
-trace (``.xplane.pb``), beside ``xplane.reduce``, which it leaves as it is.
+trace, from the data that ``xplane.load`` read, beside ``xplane.reduce``.
 
 * Host spans: the program marks its phases with profiler annotations
   named ``repro.*`` (``lab.sweep``, the batched backend's generation,
@@ -8,14 +8,14 @@ trace (``.xplane.pb``), beside ``xplane.reduce``, which it leaves as it is.
   spans included, this keeps the seconds inside the window, the count,
   and the sums of the spans' numeric arguments (``tasks``, ``lanes``,
   ``K``, ``scenarios``).
-* Idle gaps: each stretch of the window in which no op runs on the
-  device goes to the innermost covering span, ``bench.*`` or ``repro.*``.
 * Device scopes: ``_simulate_batch_jax`` names its parts with
   ``jax.named_scope``, which reaches the optimized HLO as each
   instruction's ``metadata={op_name=...}``; the trace names ops by
   instruction only. So an op's own time goes to the scope its instruction
   has in the optimized HLO text of the program that ran, and to
   ``(unscoped)`` where it has none.
+
+Idle stretches of the device, by innermost span, are ``xplane.reduce``'s.
 """
 
 from __future__ import annotations
@@ -26,8 +26,6 @@ from dataclasses import dataclass, field
 
 from bench import xplane
 
-PREFIX = "repro."
-SWEEP = "repro.sweep"
 # the named scopes of ``_simulate_batch_jax``
 SCOPES = ("prefix_scan", "deficit", "owner_lookup", "owner_gather",
           "dispatch", "scatter_add", "trigger", "service", "p99_sort",
@@ -112,57 +110,18 @@ def _maps_by_module(hlo_texts):
 class Spans:
     """Seconds over the traced window."""
 
-    window_s: float
     span_s: dict = field(default_factory=dict)      # name -> seconds
     span_n: dict = field(default_factory=dict)      # name -> count
     span_args: dict = field(default_factory=dict)   # name -> {arg: sum}
     scope_s: dict = field(default_factory=dict)     # scope -> own seconds
-    gaps: list = field(default_factory=list)        # [["host: span", s]]
-    sweeps: list = field(default_factory=list)      # per sweep {span: s}
-
-    def idle_by_span(self) -> dict:
-        """Idle seconds per innermost host span, longest first."""
-        out = defaultdict(float)
-        for label, s in self.gaps:
-            out[label] += s
-        return dict(sorted(out.items(), key=lambda kv: -kv[1]))
-
-    def idle_share_under(self, prefix: str = PREFIX) -> float | None:
-        """Share of the idle seconds whose innermost span is ``prefix*``."""
-        total = sum(s for _, s in self.gaps)
-        if total <= 0:
-            return None
-        under = sum(s for label, s in self.gaps
-                    if label.startswith(f"host: {prefix}"))
-        return under / total
 
 
-def _host_spans(data):
-    out = []
-    for plane in data.planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith((PREFIX, "bench.")):
-                        out.append((ev.start_ns, ev.end_ns, ev.name,
-                                    dict(ev.stats)))
-    return out
-
-
-def reduce(path, hlo_texts=(), window: str = xplane.WINDOW) -> Spans:
-    """Read ``path`` and reduce the program's spans and scopes over the
-    host span named ``window``; ``hlo_texts`` are the optimized HLO texts
-    of the programs the window ran (no scopes without them)."""
-    from jax.profiler import ProfileData
-    data = ProfileData.from_file(str(path))
-    spans = _host_spans(data)
-    win = [(s, e) for s, e, n, _ in spans if n == window]
-    if len(win) != 1:
-        raise RuntimeError(f"expected one {window!r} span, found {len(win)}")
-    lo, hi = win[0]
-    inside = [sp for sp in spans if sp[2] != window
-              and sp[1] > lo and sp[0] < hi]
-
+def reduce(data, hlo_texts=(), window: str = xplane.WINDOW) -> Spans:
+    """Reduce loaded profiler data (``xplane.load``) to the program's spans
+    and scopes over the host span named ``window``; ``hlo_texts`` are the
+    optimized HLO texts of the programs the window ran (no scopes without
+    them)."""
+    lo, hi, inside = xplane.host_spans(data, window)
     span_s, span_n = defaultdict(float), defaultdict(int)
     span_args = defaultdict(lambda: defaultdict(float))
     for s, e, name, stats in inside:
@@ -172,62 +131,35 @@ def reduce(path, hlo_texts=(), window: str = xplane.WINDOW) -> Spans:
             if isinstance(v, (int, float)) and not isinstance(v, bool):
                 span_args[name][k] += v
 
-    sweeps = []
-    for s, e, name, _ in sorted(inside, key=lambda sp: sp[:2]):
-        if name == SWEEP:
-            per = defaultdict(float, {SWEEP: (e - s) * 1e-9})
-            for cs, ce, cname, _ in inside:
-                if cname.startswith(PREFIX) and cname != SWEEP \
-                        and cs >= s and ce <= e:
-                    per[cname] += (ce - cs) * 1e-9
-            sweeps.append(dict(per))
-
-    pick = _maps_by_module(hlo_texts) if hlo_texts else None
     scope_s = defaultdict(float)
-    busy_union = []
-    for plane in data.planes:
-        if not plane.name.startswith("/device:TPU:"):
-            continue
-        ops, runs = [], []
-        for line in plane.lines:
-            events = [(ev.start_ns, ev.end_ns, ev.name) for ev in line.events
-                      if ev.end_ns > lo and ev.start_ns < hi]
-            if line.name == "XLA Ops":
-                ops += events
-            elif line.name == "XLA Modules":
-                runs += events
-        busy_union = xplane._union(
-            busy_union + xplane._union(xplane._clip(
-                [(s, e) for s, e, _ in ops], lo, hi)))
-        if pick is None:
-            continue
-        for rs, re_, _ in runs:
-            mine = [op for op in ops if op[0] >= rs and op[1] <= re_]
-            scopes = pick(mine)
-            for name, own in xplane._self_times(mine):
-                scope_s[scopes.get(xplane.op_name(name), UNSCOPED)] += \
-                    own * 1e-9
-
-    phases = [(s, e, n) for s, e, n, _ in inside]
-    gaps, edge = [], lo
-    for s, e in busy_union + [[hi, hi]]:
-        if s > edge:
-            gaps += xplane._attribute(edge, s, phases)
-        edge = max(edge, e)
-    return Spans(window_s=(hi - lo) * 1e-9, span_s=dict(span_s),
-                 span_n=dict(span_n),
+    if hlo_texts:
+        pick = _maps_by_module(hlo_texts)
+        for plane in data.planes:
+            if not plane.name.startswith("/device:TPU:"):
+                continue
+            ops, runs = [], []
+            for line in plane.lines:
+                events = [(ev.start_ns, ev.end_ns, ev.name)
+                          for ev in line.events
+                          if ev.end_ns > lo and ev.start_ns < hi]
+                if line.name == "XLA Ops":
+                    ops += events
+                elif line.name == "XLA Modules":
+                    runs += events
+            for rs, re_, _ in runs:
+                mine = [op for op in ops if op[0] >= rs and op[1] <= re_]
+                scopes = pick(mine)
+                for name, own in xplane._self_times(mine):
+                    scope_s[scopes.get(xplane.op_name(name), UNSCOPED)] += \
+                        own * 1e-9
+    return Spans(span_s=dict(span_s), span_n=dict(span_n),
                  span_args={k: dict(v) for k, v in span_args.items()},
-                 scope_s=dict(scope_s), gaps=gaps, sweeps=sweeps)
-
-
-def of(run) -> Spans | None:
-    """The program's spans of a run, where the run has them."""
-    return getattr(run, "spans", None)
+                 scope_s=dict(scope_s))
 
 
 def span_ms_per_sweep(run, name: str) -> float | None:
     """Milliseconds a sweep of the window spent in the host span ``name``."""
-    sp = of(run)
+    sp = run.spans
     if sp is None or run.sweeps == 0 or name not in sp.span_s:
         return None
     return 1e3 * sp.span_s[name] / run.sweeps

@@ -106,6 +106,38 @@ def test_sound_rehearsal_is_correct(name):
     assert set(out["metrics"]) == {"sim_tasks_per_s", "setup_s"}
 
 
+@pytest.mark.parametrize("name", NAMES)
+def test_traced_rehearsal_reads_the_programs_spans(name):
+    """A ``--trace 1`` run reads the program's spans: ``layout_fill`` is
+    the harness's own lane reckoning, each sweep's tasks over B * T * K
+    with K its busiest slot's arrivals rounded up to 128; idle gaps are
+    named by the program's phases. The CPU has no TPU plane, so the
+    device's readers find nothing, as before."""
+    import jax
+    jax.clear_caches()
+    cell, seed = cells.find(name).shrunk(), 2**31 + 23
+    out = run.run(cell, seed, 0.5, True, SPEC, jax.devices())
+    assert out["correct"] is True
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    assert set(got) == {"generate_ms", "quantize_ms", "layout_ms",
+                        "layout_fill", "window_compiles"}
+    assert got["window_compiles"] == 0.0
+    assert all(got[k] > 0 for k in ("generate_ms", "quantize_ms",
+                                     "layout_ms"))
+    pool = cells.pool_seeds(cell)
+    counts = np.stack([check.reference_counts(cell, s) for s in pool])
+    groups = cells.pool_groups(counts.sum(axis=1), cell.pool_sweeps)
+    order = cells.pool_order(seed, cell.pool_sweeps)
+    window = [counts[groups[g]] for g in order]
+    window = window[:out["attempted"] // cell.seeds_per_sweep]
+    tasks = sum(int(c.sum()) for c in window)
+    lanes = sum(c.size * (-(-int(c.max()) // 128) * 128) for c in window)
+    assert got["layout_fill"] == pytest.approx(100.0 * tasks / lanes,
+                                               rel=1e-12)
+    gaps = out["breakdown"]["idle_gaps"]
+    assert gaps and gaps[0][0].startswith("host: repro.")
+
+
 def test_warm_up_by_shape_unavailable_is_no_result(monkeypatch):
     from repro import lab
     from repro.runtime import vector_backend as vb

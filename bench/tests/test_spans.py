@@ -2,7 +2,9 @@
 readers, on a trace recorded on a TPU v5e: a rehearsal-size window of
 ``alibaba4k.poisson`` (``trace_sweeps.py --rehearsal``: 64 nodes, 16
 slots, 4 seeds a sweep) with the optimized HLO text of its program; and
-``xplane.reduce`` on the older ``small_fifo`` trace, as before."""
+``xplane.reduce`` on the older ``small_fifo`` trace, as before. The
+recorded window also carries the harness's former ``bench.lower``,
+``bench.layout`` and ``bench.simulate`` spans."""
 
 import importlib
 from collections import defaultdict
@@ -25,8 +27,18 @@ NEW = ("generate_ms", "quantize_ms", "layout_ms", "owner_lookup_ms",
 
 
 @pytest.fixture(scope="module")
-def reduced():
-    return spans.reduce(TRACE, [HLO.read_text()])
+def data():
+    return xplane.load(TRACE)
+
+
+@pytest.fixture(scope="module")
+def reduced(data):
+    return spans.reduce(data, [HLO.read_text()])
+
+
+@pytest.fixture(scope="module")
+def trace(data):
+    return xplane.reduce(data)
 
 
 @pytest.fixture(scope="module")
@@ -46,23 +58,19 @@ def raw():
 
 
 def _window(reduced, sweeps):
-    win = run.Window(sweeps=sweeps, tasks=0, compiles=0,
-                     device_kind="TPU v5 lite")
-    win.spans = reduced
-    return win
+    return run.Window(sweeps=sweeps, tasks=0, compiles=0,
+                      device_kind="TPU v5 lite", spans=reduced)
 
 
 def test_span_sums(reduced, raw):
     _, _, host, _ = raw
     sweeps = reduced.span_n["repro.sweep"]
-    assert sweeps >= 2 and len(reduced.sweeps) == sweeps
+    assert sweeps >= 2
     for name in PHASES:
         mine = [h for h in host if h[2] == name]
         assert reduced.span_n[name] == len(mine) == sweeps
         assert reduced.span_s[name] == pytest.approx(
             sum(e - s for s, e, _, _ in mine) * 1e-9)
-        assert sum(sw[name] for sw in reduced.sweeps) == pytest.approx(
-            reduced.span_s[name])
     args = defaultdict(float)
     for _, _, name, stats in host:
         if name == "repro.vector.layout":
@@ -73,13 +81,47 @@ def test_span_sums(reduced, raw):
     assert reduced.span_n["bench.sweep"] == sweeps
 
 
-def test_idle_agrees_with_the_harness_and_falls_under_program_spans(reduced):
-    tr = xplane.reduce(TRACE)
-    assert reduced.window_s == pytest.approx(tr.window_s)
-    idle = sum(s for _, s in reduced.gaps)
-    assert idle == pytest.approx(tr.window_s - tr.busy_s)
-    assert sum(reduced.idle_by_span().values()) == pytest.approx(idle)
-    assert reduced.idle_share_under("repro.") > 0.9
+# idle seconds by innermost span, as the program's spans and the
+# harness's read them before idle gaps were named by both
+IDLE_BY_SPAN = {
+    "host: bench.layout": 9.699e-05, "host: bench.lower": 0.00181266,
+    "host: bench.simulate": 0.0001853, "host: bench.sweep": 8.63e-05,
+    "host: no bench span": 0.0006423,
+    "host: repro.batched.generate": 0.00168784,
+    "host: repro.batched.quantize": 0.00050336,
+    "host: repro.batched.results": 0.00211238,
+    "host: repro.sweep": 0.001698919,
+    "host: repro.vector.fetch": 0.017644733,
+    "host: repro.vector.layout": 0.00037679,
+    "host: repro.vector.transfer": 0.003113437}
+
+
+def test_idle_agrees_with_the_harness_and_falls_under_program_spans(trace):
+    idle = sum(s for _, s in trace.gaps)
+    assert idle == pytest.approx(trace.window_s - trace.busy_s)
+    by_span = trace.idle_by_span()
+    assert sum(by_span.values()) == pytest.approx(idle)
+    assert list(by_span.values()) == sorted(by_span.values(), reverse=True)
+    assert by_span == pytest.approx(IDLE_BY_SPAN, rel=1e-9)
+    under = sum(s for label, s in by_span.items()
+                if label.startswith("host: repro."))
+    assert under / idle > 0.9
+
+
+# the harness's readers on this trace as they read before the program's
+# spans named its idle gaps (3 sweeps and 12,345 tasks stand in for a run)
+BEFORE = {"device_idle_share": 84.70889423048378,
+          "program_ms": 1.8032113333333333,
+          "prefix_scan_roofline": 0.6014268358408009,
+          "window_compiles": 0.0}
+
+
+def test_harness_readers_read_as_before(trace):
+    win = run.Window(sweeps=3, tasks=12345, compiles=0,
+                     device_kind="TPU v5 lite", trace=trace)
+    got = {m: importlib.import_module(f"bench.metrics.{m}").read(win)
+           for m in BEFORE}
+    assert got == pytest.approx(BEFORE, rel=1e-12)
 
 
 def test_scope_map(reduced, raw):
@@ -138,8 +180,9 @@ def test_layout_fill_is_the_bench_lane_reckoning(reduced):
 
 def test_reduce_of_small_fifo_reads_as_before():
     """``xplane.reduce`` gives every field it gave before the program had
-    spans; ``spans.reduce`` finds the same idle stretches in it."""
-    tr = xplane.reduce(DATA / "small_fifo.xplane.pb", window="bench.sweep")
+    spans; ``spans.reduce`` finds no program span or scope in it."""
+    data = xplane.load(DATA / "small_fifo.xplane.pb")
+    tr = xplane.reduce(data, window="bench.sweep")
     assert tr.window_s == pytest.approx(0.011553639, abs=1e-12)
     assert tr.busy_s == pytest.approx(0.001230366, abs=1e-12)
     assert tr.chips == 1 and tr.modules == 1
@@ -154,8 +197,7 @@ def test_reduce_of_small_fifo_reads_as_before():
     assert len(tr.gaps) == 33
     assert {name for name, _ in tr.gaps} == {"host: no bench span"}
     assert sum(s for _, s in tr.gaps) == pytest.approx(0.010323273)
-    sp = spans.reduce(DATA / "small_fifo.xplane.pb", window="bench.sweep")
-    assert sp.gaps == tr.gaps
+    sp = spans.reduce(data, window="bench.sweep")
     assert sp.span_s == {} and sp.scope_s == {}
 
 

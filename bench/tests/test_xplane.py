@@ -13,7 +13,7 @@ SPAN = "bench.sweep"
 
 @pytest.fixture(scope="module")
 def trace():
-    return xplane.reduce(DATA, window=SPAN)
+    return xplane.reduce(xplane.load(DATA), window=SPAN)
 
 
 @pytest.fixture(scope="module")

@@ -6,8 +6,10 @@ plane; control flow (a ``while``) is an op whose interval holds its body's
 ops, so busy time is the union of the intervals and an op's own time is
 its interval less what nested ops cover. The ``XLA Modules`` line has one
 event per program run. The host's timeline is the ``/host:CPU`` plane,
-where the benchmark's ``bench.*`` annotations mark the window, each sweep
-and the host phases, on the same clock as the device.
+where the benchmark's ``bench.*`` annotations mark the window and each
+sweep, and the program's ``repro.*`` annotations its host phases, on the
+same clock as the device. Each idle stretch of the device goes to the
+innermost of those spans that covers it.
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 WINDOW = "bench.window"
+PREFIXES = ("bench.", "repro.")   # the harness's spans and the program's
 
 
 def _union(intervals):
@@ -84,6 +87,13 @@ class Trace:
     def longest_gaps(self, k: int = 10):
         return [list(g) for g in sorted(self.gaps, key=lambda g: -g[1])[:k]]
 
+    def idle_by_span(self) -> dict:
+        """Idle seconds per innermost host span, longest first."""
+        out = defaultdict(float)
+        for label, s in self.gaps:
+            out[label] += s
+        return dict(sorted(out.items(), key=lambda kv: -kv[1]))
+
 
 def find_xplane(log_dir) -> Path:
     files = glob.glob(str(Path(log_dir) / "**" / "*.xplane.pb"),
@@ -94,24 +104,33 @@ def find_xplane(log_dir) -> Path:
     return Path(files[0])
 
 
-def reduce(path, window: str = WINDOW) -> Trace:
-    """Read ``path`` and reduce it to a :class:`Trace` over the host span
-    named ``window``."""
+def load(path):
+    """The profiler's data in ``path``, read once for every reduction."""
     from jax.profiler import ProfileData
-    data = ProfileData.from_file(str(path))
-    host_spans = []
-    for plane in data.planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host_spans += [(ev.start_ns, ev.end_ns, ev.name)
-                               for ev in line.events
-                               if ev.name.startswith("bench.")]
-    win = [(s, e) for s, e, n in host_spans if n == window]
+    return ProfileData.from_file(str(path))
+
+
+def host_spans(data, window: str = WINDOW):
+    """``(lo, hi, inside)``: the bounds of the host span named ``window``
+    and the ``bench.*`` and ``repro.*`` host spans that overlap it, each
+    ``(start, end, name, {arg: value})``."""
+    spans = [(ev.start_ns, ev.end_ns, ev.name, dict(ev.stats))
+             for plane in data.planes if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith(PREFIXES)]
+    win = [(s, e) for s, e, n, _ in spans if n == window]
     if len(win) != 1:
         raise RuntimeError(f"expected one {window!r} span, found {len(win)}")
     lo, hi = win[0]
-    phases = [(s, e, n) for s, e, n in host_spans
-              if n != window and e > lo and s < hi]
+    return lo, hi, [sp for sp in spans
+                    if sp[2] != window and sp[1] > lo and sp[0] < hi]
+
+
+def reduce(data, window: str = WINDOW) -> Trace:
+    """Reduce loaded profiler data (:func:`load`) to a :class:`Trace` over
+    the host span named ``window``."""
+    lo, hi, inside = host_spans(data, window)
+    phases = [(s, e, n) for s, e, n, _ in inside]
 
     busy_total, chips = 0.0, 0
     busy_union = []
