@@ -17,6 +17,7 @@ experiment that produced it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import inspect
 import json
@@ -66,6 +67,8 @@ def _frozen_params(params: Mapping) -> Mapping:
 
 def _thaw(value):
     """Specs/tuples/mappings down to plain JSON types."""
+    if isinstance(value, (str, int, float)) or value is None:
+        return value    # first: a cluster's powers are 10^4 of these
     if dataclasses.is_dataclass(value) and not isinstance(value, type):
         return {f.name: _thaw(getattr(value, f.name))
                 for f in fields(value)}
@@ -74,6 +77,14 @@ def _thaw(value):
     if isinstance(value, Mapping):
         return {k: _thaw(v) for k, v in value.items()}
     return value
+
+
+def _canonical_json(value) -> str:
+    """Compact, key-sorted JSON of a spec or plain value: the form that
+    fingerprints and hashes read. A spec's is built once and kept."""
+    if isinstance(value, _SpecBase):
+        return value.canonical_json
+    return json.dumps(_thaw(value), sort_keys=True, separators=(",", ":"))
 
 
 # content-digest cache: re-hashing a million-row trace for every scenario
@@ -111,6 +122,15 @@ class _SpecBase:
 
     def to_dict(self) -> dict:
         return _thaw(self)
+
+    @functools.cached_property
+    def canonical_json(self) -> str:
+        """Compact, key-sorted JSON of ``to_dict()``. A spec is frozen, so
+        this is built on first use and kept on the instance: a cluster of
+        10^4 explicit powers is serialised once, however many scenarios of
+        a sweep share it."""
+        return json.dumps(self.to_dict(), sort_keys=True,
+                          separators=(",", ":"))
 
     @classmethod
     def from_dict(cls, d: dict) -> "_SpecBase":
@@ -408,8 +428,7 @@ class WorkloadSpec(_SpecBase):
                                packet_mean=self.packet_mean,
                                seed=seed, **self.params)
             return self._attach_dag(wl, seed)
-        key = (json.dumps(self.to_dict(), sort_keys=True), int(seed),
-               self.content_digest())
+        key = (self.canonical_json, int(seed), self.content_digest())
         if key not in _TRACE_CACHE:
             if self.trace is not None:
                 wl = self._clip(self.trace.load(seed), self.trace.path)
@@ -602,7 +621,12 @@ class Scenario(_SpecBase):
     # -- serialization ------------------------------------------------------
     @classmethod
     def from_dict(cls, d: dict) -> "Scenario":
-        d = dict(d)
+        return cls(**cls._fields(dict(d)))
+
+    @classmethod
+    def _fields(cls, d: dict) -> dict:
+        """``d`` with each section given as a dict built into its spec;
+        a name that is no field of a scenario is refused."""
         for key, section_cls in _SECTIONS.items():
             if key in d and isinstance(d[key], dict):
                 d[key] = section_cls.from_dict(d[key])
@@ -610,7 +634,7 @@ class Scenario(_SpecBase):
         unknown = set(d) - known
         if unknown:
             raise ValueError(f"Scenario: unknown fields {sorted(unknown)}")
-        return cls(**d)
+        return d
 
     def to_dict(self) -> dict:
         # an un-instrumented scenario serializes exactly as it did before
@@ -628,6 +652,19 @@ class Scenario(_SpecBase):
     def from_json(cls, text: str) -> "Scenario":
         return cls.from_dict(json.loads(text))
 
+    @functools.cached_property
+    def canonical_json(self) -> str:
+        """Compact, key-sorted JSON of ``to_dict()``, kept."""
+        return self._canonical(obs=self.obs is not None)
+
+    def _canonical(self, *, obs: bool) -> str:
+        """``canonical_json`` of ``to_dict()``, with ``obs`` or without,
+        put together from each section's own (kept) canonical JSON."""
+        items = sorted((f.name, getattr(self, f.name)) for f in fields(self)
+                       if obs or f.name != "obs")
+        return "{" + ",".join(f"{json.dumps(k)}:{_canonical_json(v)}"
+                              for k, v in items) + "}"
+
     def fingerprint(self) -> str:
         """Stable 16-hex-digit identity of the canonical JSON form.
 
@@ -636,11 +673,9 @@ class Scenario(_SpecBase):
         collide in sweep caches or result attribution, and a trace edited
         between runs is a different experiment.
         """
-        d = self.to_dict()
         # telemetry is not identity: an instrumented run must attribute to
         # the same experiment as its un-instrumented twin
-        d.pop("obs", None)
-        canon = json.dumps(d, sort_keys=True, separators=(",", ":"))
+        canon = self._canonical(obs=False)
         digest = self.workload.content_digest()
         if digest is not None:
             canon += f"|trace-sha256:{digest}"
@@ -650,9 +685,17 @@ class Scenario(_SpecBase):
     def updated(self, assignments: dict) -> "Scenario":
         """A copy with dotted-path fields replaced: ``{"seed": 3,
         "policy.params.floor": 0.1, "cluster.d": 2}``. The mechanism behind
-        :func:`repro.lab.sweep` grids."""
-        d = self.to_dict()
+        :func:`repro.lab.sweep` grids.
+
+        Only the sections a path names are rebuilt, from their dicts; the
+        copy shares every other section with this scenario, so a seed copy
+        of a cluster of 10^4 explicit powers costs what one of ``n_nodes``
+        does."""
+        d = {}
         for path, value in assignments.items():
+            head, dot, _ = path.partition(".")
+            if dot and head in _SECTIONS and head not in d:
+                d[head] = _thaw(getattr(self, head))
             node = d
             *parents, leaf = path.split(".")
             for p in parents:
@@ -660,15 +703,14 @@ class Scenario(_SpecBase):
                     raise KeyError(f"no such scenario section: {path!r}")
                 node = node[p]
             node[leaf] = _thaw(value)
-        return Scenario.from_dict(d)
+        return replace(self, **self._fields(d))
 
 
 def _spec_hash(self) -> int:
     """Hash by canonical JSON identity — the generated dataclass hash
     would choke on the read-only params mappings, and frozen specs invite
     set/dict use (dedup of expanded grids, scenario-keyed result maps)."""
-    return hash((type(self).__name__,
-                 json.dumps(self.to_dict(), sort_keys=True)))
+    return hash((type(self).__name__, self.canonical_json))
 
 
 for _cls in (ClusterSpec, WorkloadSpec, TraceRef, FaultSpec, PolicySpec,

@@ -20,10 +20,13 @@ from repro import lab
 from repro.runtime import vector_backend as vb
 
 # the phases of one batched sweep, in the order they run; all inside
-# ``repro.sweep``
-PHASES = ("repro.batched.generate", "repro.batched.quantize",
-          "repro.vector.layout", "repro.vector.transfer", "repro.vector.run",
-          "repro.vector.fetch", "repro.batched.results")
+# ``repro.sweep``. ``repro.lab.specs`` is the scenario-spec handling: the
+# uniformity check of the dispatch and of the compile, and the results'
+# fingerprints
+PHASES = ("repro.lab.specs", "repro.lab.specs", "repro.batched.generate",
+          "repro.batched.quantize", "repro.vector.layout",
+          "repro.vector.transfer", "repro.vector.run", "repro.vector.fetch",
+          "repro.lab.specs", "repro.batched.results")
 SCOPES = ("prefix_scan", "deficit", "owner_lookup", "owner_gather",
           "dispatch", "scatter_add", "trigger", "service", "p99_sort",
           "summary")
@@ -73,6 +76,9 @@ def test_every_span_once_per_sweep_nested(profiled):
         assert tuple(name for _, _, name, _ in inner) == PHASES
         for (_, end, _, _), (start, _, _, _) in zip(inner, inner[1:]):
             assert end <= start          # one after the other, none nested
+        for _, _, name, args in inner:
+            if name == "repro.lab.specs":
+                assert args == {"scenarios": len(seeds), "nodes": 16}
     assert len(spans) == len(SEEDS) * (1 + len(PHASES))
 
 
