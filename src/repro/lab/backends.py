@@ -419,12 +419,14 @@ class BatchedBackend(Backend):
         power_scale). All scenarios must share cluster/policy/faults/
         workload shape (only seeds may differ).
 
-        Profiler spans: ``repro.batched.generate`` (each seed's workload;
-        ``tasks`` counts them) and ``repro.batched.quantize`` (tasks onto
-        slots, and the power schedule)."""
+        Profiler spans: ``repro.batched.generate`` (each seed's arrivals
+        and works; ``tasks`` counts them, ``packets`` the packet counts
+        drawn with them, none for a synthetic workload) and
+        ``repro.batched.quantize`` (tasks onto slots, and the power
+        schedule)."""
         from jax.profiler import TraceAnnotation
         from ..runtime.vector_backend import VectorConfig
-        from ..runtime.workload import batch_slots
+        from ..runtime.workload import draw_tasks, slot_rows
         if not uniform_but_for_seed(scenarios):
             raise BackendError(
                 "batched batch: scenarios must be identical except for "
@@ -433,12 +435,28 @@ class BatchedBackend(Backend):
         base = scenarios[0]
         powers = base.cluster.resolve_powers()
         n = int(powers.size)
+        spec = base.workload
         with TraceAnnotation("repro.batched.generate") as span:
-            wls = [sc.workload.materialize(sc.seed) for sc in scenarios]
-            span.set_metadata(tasks=sum(wl.m for wl in wls))
-        horizon = base.workload.horizon
+            if spec.is_trace:
+                wls = [sc.workload.materialize(sc.seed) for sc in scenarios]
+                draws = [(wl.t_arrive, wl.works) for wl in wls]
+                packets = sum(wl.packets.size for wl in wls)
+            else:
+                # the slot grid reads arrival times and works alone; the
+                # packet counts that follow them in each seed's stream are
+                # never drawn (migrations are priced from packet_mean)
+                draws = [draw_tasks(spec.process, horizon=spec.horizon,
+                                    work_dist=spec.work_dist,
+                                    work_mean=spec.work_mean, seed=sc.seed,
+                                    **spec.params)[1:]
+                         for sc in scenarios]
+                packets = 0
+            span.set_metadata(tasks=sum(t.size for t, _ in draws),
+                              packets=packets)
+        horizon = spec.horizon
         if horizon is None:  # whole-trace replay: cover the last arrival
-            horizon = max((wl.horizon for wl in wls), default=0.0) + dt
+            horizon = max((float(t[-1]) for t, _ in draws if t.size),
+                          default=0.0) + dt
         # ceil, not round: a final partial slot must still admit arrivals
         # in [floor(horizon/dt)*dt, horizon) or the backends diverge
         n_slots = max(int(math.ceil(horizon / dt - 1e-9)), 1)
@@ -449,7 +467,7 @@ class BatchedBackend(Backend):
         defaults = PstsPolicy()
         cost = {k: float(pol.params.get(k, getattr(defaults, k)))
                 for k in _COST_KEYS}
-        if base.workload.is_trace:
+        if spec.is_trace:
             # a trace carries its own packet/work ratio; the spec's
             # sampling means are never read for traces
             tot_w = sum(float(wl.works.sum()) for wl in wls)
@@ -458,8 +476,7 @@ class BatchedBackend(Backend):
         else:
             # sample_packets draws 1 + Poisson(packet_mean), so the
             # realized mean is packet_mean + 1
-            packets_per_unit = ((1.0 + base.workload.packet_mean)
-                                / base.workload.work_mean)
+            packets_per_unit = (1.0 + spec.packet_mean) / spec.work_mean
         cfg = VectorConfig(
             n_nodes=n, n_slots=n_slots, dt=float(dt),
             rebalance=(pol.name == "psts"),
@@ -471,7 +488,7 @@ class BatchedBackend(Backend):
                    and base.obs.probe_every is not None),
             **cost)
         with TraceAnnotation("repro.batched.quantize"):
-            slot, works, _ = batch_slots(wls, dt, n_slots)
+            slot, works, _ = slot_rows(draws, dt, n_slots)
             scale = self._power_scale(base, n_slots, n, dt)
         return slot, works, powers, cfg, scale
 

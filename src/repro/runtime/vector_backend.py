@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from ..kernels import ops
 from .metrics import nearest_rank
-from .workload import batch_slots
+from .workload import draw_tasks, slot_rows
 
 __all__ = ["VectorConfig", "BatchMetrics", "simulate_batch",
            "simulate_scalar", "sweep_seeds"]
@@ -327,7 +327,7 @@ def _owner(src, frac):
 
 
 def _per_slot(slot: np.ndarray, works: np.ndarray, n_slots: int):
-    """(B, M) task rows sorted by slot (``workload.batch_slots``) ->
+    """(B, M) task rows sorted by slot (``workload.slot_rows``) ->
     ``(works (B, T, K), cnt (B, T))``: slot t's arrivals of row b in
     ``works[b, t, :cnt[b, t]]``, in stream order, zero-padded. K is the
     largest slot's task count rounded up to the 128-lane width."""
@@ -516,11 +516,11 @@ def simulate_batch(slot: np.ndarray, works: np.ndarray, powers: np.ndarray,
 def sweep_seeds(process: str, seeds, powers, cfg: VectorConfig, *,
                 power_scale: np.ndarray | None = None,
                 **workload_kwargs) -> BatchMetrics:
-    """Generate one workload per seed and run the whole sweep in one batched
-    call — the on-accelerator replacement for a Python loop over scenarios."""
-    from .workload import make_workload
+    """Draw each seed's arrivals and works (``workload.draw_tasks``, which
+    takes ``workload_kwargs``) and run the whole sweep in one batched call
+    — the on-accelerator replacement for a Python loop over scenarios."""
     horizon = cfg.n_slots * cfg.dt
-    wls = [make_workload(process, horizon=horizon, seed=int(s),
-                         **workload_kwargs) for s in seeds]
-    slot, works, _ = batch_slots(wls, cfg.dt, cfg.n_slots)
+    draws = [draw_tasks(process, horizon=horizon, seed=int(s),
+                        **workload_kwargs)[1:] for s in seeds]
+    slot, works, _ = slot_rows(draws, cfg.dt, cfg.n_slots)
     return simulate_batch(slot, works, powers, cfg, power_scale=power_scale)

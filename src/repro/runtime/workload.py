@@ -11,9 +11,11 @@ those marginals and adds the arrival processes a production cluster sees:
 * ``diurnal``  — inhomogeneous Poisson with a sinusoidal rate (thinning),
 * ``trace``    — replay of explicit arrival timestamps.
 
-``to_slots``/``batch_slots`` convert workloads to the fixed-shape tensors the
+``slot_rows`` quantises scenarios' arrivals onto the fixed-shape tensors the
 vectorized backend consumes (slot index per task, padded to a common task
-count with zero-work sentinels).
+count with zero-work sentinels); ``to_slots``/``batch_slots`` apply it to
+``Workload`` objects. The batched sweep lowers synthetic scenarios from
+``draw_tasks``, the part of ``make_workload``'s draws that a slot grid reads.
 """
 
 from __future__ import annotations
@@ -31,8 +33,10 @@ __all__ = [
     "diurnal_arrivals",
     "trace_arrivals",
     "ARRIVAL_PROCESSES",
+    "draw_tasks",
     "make_workload",
     "load_trace_csv",
+    "slot_rows",
     "to_slots",
     "batch_slots",
 ]
@@ -142,22 +146,31 @@ ARRIVAL_PROCESSES = {
 }
 
 
-def make_workload(process: str = "poisson", *, horizon: float = 100.0,
-                  work_dist: str = "uniform", work_mean: float = 4.0,
-                  packet_mean: float = 8.0, seed: int = 0,
-                  **process_kwargs) -> Workload:
-    """One scenario: arrival process x paper work/packet marginals."""
+def draw_tasks(process: str = "poisson", *, horizon: float = 100.0,
+               work_dist: str = "uniform", work_mean: float = 4.0,
+               seed: int = 0, **process_kwargs):
+    """The first draws of one scenario's stream: ``(rng, t_arrive, works)``,
+    the arrival times (sorted, float64) and then their work units, with the
+    generator left where a further draw continues the stream."""
     if process not in ARRIVAL_PROCESSES:
         raise ValueError(f"unknown arrival process {process!r}; "
                          f"have {sorted(ARRIVAL_PROCESSES)}")
     rng = np.random.default_rng(seed)
     t = ARRIVAL_PROCESSES[process](horizon, rng, **process_kwargs)
-    m = t.shape[0]
-    return Workload(
-        t_arrive=t,
-        works=sample_works(m, work_dist, work_mean, rng),
-        packets=sample_packets(m, packet_mean, rng),
-    )
+    return rng, t, sample_works(t.shape[0], work_dist, work_mean, rng)
+
+
+def make_workload(process: str = "poisson", *, horizon: float = 100.0,
+                  work_dist: str = "uniform", work_mean: float = 4.0,
+                  packet_mean: float = 8.0, seed: int = 0,
+                  **process_kwargs) -> Workload:
+    """One scenario: arrival process x paper work/packet marginals; the
+    packets are drawn after ``draw_tasks``'s arrivals and works."""
+    rng, t, works = draw_tasks(process, horizon=horizon, work_dist=work_dist,
+                               work_mean=work_mean, seed=seed,
+                               **process_kwargs)
+    return Workload(t_arrive=t, works=works,
+                    packets=sample_packets(t.shape[0], packet_mean, rng))
 
 
 def load_trace_csv(path, *, horizon: float | None = None) -> Workload:
@@ -191,37 +204,35 @@ def load_trace_csv(path, *, horizon: float | None = None) -> Workload:
 # Slotted views for the vectorized backend
 # ---------------------------------------------------------------------------
 
-def to_slots(wl: Workload, dt: float, n_slots: int,
-             max_tasks: int | None = None):
-    """Quantise a workload onto a slot grid.
+def slot_rows(draws, dt: float, n_slots: int):
+    """Quantise scenarios onto one slot grid in one fill.
 
-    Returns ``(arrive_slot, works, count)`` where padding entries carry
-    ``arrive_slot == n_slots`` (an out-of-range sentinel the backend drops)
-    and zero work. Tasks at or beyond the horizon are truncated.
+    ``draws`` is a sequence of each scenario's ``(t_arrive, works)``,
+    ``t_arrive`` sorted. Returns ``(slot (B, M) int32, works (B, M)
+    float64, count (B,) int64)``: row b holds its ``count[b]`` tasks
+    before the grid's end ``dt * n_slots``, at slot ``floor(t / dt)``,
+    then padding with ``slot == n_slots`` (an out-of-range sentinel the
+    backend drops) and zero work, up to M, the largest count.
     """
-    keep = wl.t_arrive < dt * n_slots
-    slot = np.floor(wl.t_arrive[keep] / dt).astype(np.int32)
-    works = wl.works[keep]
-    count = int(slot.shape[0])
-    cap = count if max_tasks is None else int(max_tasks)
-    if count > cap:
-        slot, works, count = slot[:cap], works[:cap], cap
-    out_slot = np.full(cap, n_slots, dtype=np.int32)
-    out_work = np.zeros(cap, dtype=np.float64)
-    out_slot[:count] = slot
-    out_work[:count] = works
-    return out_slot, out_work, count
+    end = dt * n_slots
+    counts = np.array([np.searchsorted(t, end) for t, _ in draws],
+                      dtype=np.int64)
+    slot = np.full((len(draws), counts.max(initial=0)), n_slots,
+                   dtype=np.int32)
+    works = np.zeros(slot.shape, dtype=np.float64)
+    for row, ((t, w), c) in enumerate(zip(draws, counts)):
+        slot[row, :c] = np.floor(t[:c] / dt)
+        works[row, :c] = w[:c]
+    return slot, works, counts
+
+
+def to_slots(wl: Workload, dt: float, n_slots: int):
+    """One workload's ``slot_rows``: ``(arrive_slot, works, count)``."""
+    slot, works, count = slot_rows([(wl.t_arrive, wl.works)], dt, n_slots)
+    return slot[0], works[0], int(count[0])
 
 
 def batch_slots(workloads, dt: float, n_slots: int):
     """Stack scenarios into ``(B, M)`` tensors with a common task capacity."""
-    cap = max((int((wl.t_arrive < dt * n_slots).sum()) for wl in workloads),
-              default=0)
-    slots, works, counts = [], [], []
-    for wl in workloads:
-        s, w, c = to_slots(wl, dt, n_slots, max_tasks=cap)
-        slots.append(s)
-        works.append(w)
-        counts.append(c)
-    return (np.stack(slots), np.stack(works),
-            np.asarray(counts, dtype=np.int64))
+    return slot_rows([(wl.t_arrive, wl.works) for wl in workloads], dt,
+                     n_slots)
